@@ -80,6 +80,7 @@ class CheckpointManager:
         self.keep = keep
         os.makedirs(directory, exist_ok=True)
         self._pending: Optional[threading.Thread] = None
+        self._failure: Optional[BaseException] = None
 
     # ------------------------------------------------------------------ #
     def save(self, step: int, tree: Any, *, block: bool = True,
@@ -126,14 +127,25 @@ class CheckpointManager:
         if block:
             write()
         else:
-            self._pending = threading.Thread(target=write, daemon=True)
+            def write_async():
+                try:
+                    write()
+                except BaseException as exc:
+                    self._failure = exc     # raised by wait()
+                    raise
+            self._pending = threading.Thread(target=write_async, daemon=True)
             self._pending.start()
         return final
 
     def wait(self):
+        """Join the save in flight; raise if it failed."""
         if self._pending is not None:
             self._pending.join()
             self._pending = None
+        failure, self._failure = self._failure, None
+        if failure is not None:
+            raise RuntimeError("asynchronous checkpoint save failed") \
+                from failure
 
     def _gc(self):
         steps = self.all_steps()

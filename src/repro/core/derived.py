@@ -128,7 +128,11 @@ GPU_UTILIZATION = DerivedMetric(
 # ratios of gpu_counter columns, so the zero-division policy (0) makes
 # them vanish at contexts with no counter data.
 # ---------------------------------------------------------------------------
-from repro.core.sampling import PEAK_FLOPS as _PEAK_FLOPS  # noqa: E402
+from repro.core.peaks import peaks_for  # noqa: E402
+
+# Databases do not record the device kind they were measured on yet, so
+# the efficiency is taken against the one chip the peak table holds.
+_PEAK_FLOPS = peaks_for("TPU v5 lite").flops
 
 # modeled busy time over elapsed time, clamped into [0, 1]
 ACHIEVED_OCCUPANCY = DerivedMetric(

@@ -42,11 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.cct import Frame, GPU_FUNC, GPU_LOOP, GPU_OP
-
-# chip constants shared with sampling.py (kept literal to avoid an
-# import cycle; sampling asserts they agree)
-PEAK_FLOPS = 197e12            # bf16 FLOP/s per chip
-VMEM_BW = 2.2e13               # ~bytes/s VMEM<->vector-unit bandwidth
+from repro.core.peaks import Peaks, device_peaks
 
 # transcendental primitives get the same 10x element weight the HLO
 # cost model uses (structure._estimate_costs)
@@ -184,7 +180,7 @@ class KernelStructure:
         loop_prefix: Tuple[Frame, ...] = tuple(
             Frame(GPU_LOOP, f"grid:{gname}", base, kline)
             for _, gname in sorted((grid_loops or {}).items()))
-        acc = _LeafAccumulator(kname, kfile, base)
+        acc = _LeafAccumulator(kname, kfile, base, device_peaks())
         _walk_jaxpr(inner, acc, loop_prefix, 1.0)
         return cls(name, base, kline, acc.build(), grid=grid)
 
@@ -206,9 +202,10 @@ def _find_pallas_call(jaxpr):
 
 
 def _kernel_ident(eqn, inner) -> Tuple[str, str, int]:
-    """(function name, file, def line) of the kernel callable."""
-    nsi = eqn.params.get("name_and_src_info")
-    kname = getattr(nsi, "name", None) or "kernel"
+    """(function name, file, def line) of the kernel callable.  The
+    kernel jaxpr's debug info names the traced function; an explicit
+    ``pallas_call(name=...)`` is only a label and never a frame."""
+    kname = inner.debug_info.func_name
     for e in inner.eqns:
         frames = _user_frames(e)
         for fr in frames:
@@ -220,11 +217,8 @@ def _kernel_ident(eqn, inner) -> Tuple[str, str, int]:
 
 
 def _user_frames(eqn):
-    try:
-        from jax._src import source_info_util
-        return list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
-        return []
+    from jax._src import source_info_util
+    return list(source_info_util.user_frames(eqn.source_info.traceback))
 
 
 def _aval_elems(aval) -> int:
@@ -272,8 +266,10 @@ class _LeafAccumulator:
     """Groups equations by (loop chain, inline scope chain, source line)
     into deterministic, first-occurrence-ordered leaves."""
 
-    def __init__(self, kernel_fn: str, kernel_file: str, base: str):
+    def __init__(self, kernel_fn: str, kernel_file: str, base: str,
+                 peaks: Peaks):
         self.kernel_fn = kernel_fn
+        self.peaks = peaks
         self.kernel_file = kernel_file
         self.base = base
         self._groups: Dict[tuple, dict] = {}
@@ -296,7 +292,10 @@ class _LeafAccumulator:
             # scope frame line = the call site in the enclosing frame
             site = int(frames[len(chain) - i].start_line) \
                 if len(chain) - i < len(frames) else int(fr.start_line)
-            scopes.append(Frame(GPU_FUNC, fr.function_name, self.base, site))
+            # frames carry qualified names ("kernel.<locals>._block");
+            # the scope is named by the function itself
+            name = fr.function_name.rsplit(".", 1)[-1]
+            scopes.append(Frame(GPU_FUNC, name, self.base, site))
         return tuple(scopes), line
 
     def add(self, eqn, loops: Tuple[Frame, ...], trip: float) -> None:
@@ -313,7 +312,7 @@ class _LeafAccumulator:
                 "prims": {}}
         g["flops"] += flops * trip
         g["bytes"] += nbytes * trip
-        w = max(flops / PEAK_FLOPS, nbytes / VMEM_BW)
+        w = max(flops / self.peaks.flops, nbytes / self.peaks.vmem_bw)
         g["prims"][prim] = g["prims"].get(prim, 0.0) + w
 
     def build(self) -> List[KernelLeaf]:
@@ -322,9 +321,9 @@ class _LeafAccumulator:
                 self._groups.items(), key=lambda kv: kv[1]["order"]):
             # dominant primitive names the leaf (ties: alphabetical)
             dom = max(sorted(g["prims"]), key=lambda p: g["prims"][p])
-            t_c = g["flops"] / PEAK_FLOPS
-            t_m = g["bytes"] / VMEM_BW
-            weight = max(t_c, t_m, 1.0 / PEAK_FLOPS)
+            t_c = g["flops"] / self.peaks.flops
+            t_m = g["bytes"] / self.peaks.vmem_bw
+            weight = max(t_c, t_m, 1.0 / self.peaks.flops)
             leaf = Frame(GPU_OP, dom, self.base, line)
             leaves.append(KernelLeaf(
                 frames=loops + scopes + (leaf,), weight=weight,

@@ -105,6 +105,8 @@ class MonitorThread:
                       "counter_records": 0, "drains": 0}
         # (stream, [(A, P), ...]) -> None, one call per drained batch
         self.trace_sink: Optional[Callable] = None
+        # the exception that ended the monitor loop, if one did
+        self.failure: Optional[BaseException] = None
 
     # -- lifecycle ----------------------------------------------------------
     def start(self):
@@ -121,8 +123,19 @@ class MonitorThread:
         for t in self._trace_threads:
             t.stop()
 
-    def quiesce(self, timeout: float = 5.0):
-        """Wait until all rings and trace channels drain (used by flush)."""
+    def check(self) -> None:
+        """Raise if the monitor or a tracing thread died: records they
+        would have attributed are lost, so a profile written now would
+        be silently partial."""
+        for t in (self, *self._trace_threads):
+            if t.failure is not None:
+                raise RuntimeError(
+                    f"{type(t).__name__} died; the profile is incomplete"
+                ) from t.failure
+
+    def quiesce(self, timeout: float = 5.0) -> bool:
+        """Wait until all rings and trace channels drain (used by flush).
+        Returns False on timeout; raises if a measurement thread died."""
         def queues_empty():
             if not all(ring.empty for _, ring in self._rings.items()):
                 return False
@@ -135,6 +148,7 @@ class MonitorThread:
 
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
+            self.check()
             # queues / flags / queues / flags.  The flags are raised before
             # each batch pop, so flags reading False rules out a batch
             # popped from rings a preceding scan saw empty; the second
@@ -146,18 +160,23 @@ class MonitorThread:
                     and queues_empty() and flags_clear():
                 return True
             time.sleep(self._poll_s)
+        self.check()
         return False
 
     # -- the monitor loop ----------------------------------------------------
     def _run(self):
-        while not self._stop.is_set():
-            busy = self._drain_once()
-            if not busy:
-                time.sleep(self._poll_s)
-        # final drain on shutdown
-        for _ in range(16):
-            if not self._drain_once():
-                break
+        try:
+            while not self._stop.is_set():
+                busy = self._drain_once()
+                if not busy:
+                    time.sleep(self._poll_s)
+            # final drain on shutdown
+            for _ in range(16):
+                if not self._drain_once():
+                    break
+        except BaseException as exc:
+            self.failure = exc      # reported by check(); flush raises
+            raise
 
     def _drain_once(self) -> bool:
         """One polling round: one epoch-stamped batch read per ring,
@@ -226,17 +245,22 @@ class TracingThread(threading.Thread):
         self.records: Dict[int, list] = {}
         # raised before each batch pop (see MonitorThread.quiesce)
         self.busy = False
+        self.failure: Optional[BaseException] = None
 
     def add_channel(self, stream: int, q: SpscQueue, sink):
         # single assignment from the monitor thread; dict insert is atomic
         self._channels[stream] = (q, sink)
 
     def run(self):
-        while not self._stop_evt.is_set():
-            busy = self._poll()
-            if not busy:
-                time.sleep(self._poll_s)
-        self._poll()
+        try:
+            while not self._stop_evt.is_set():
+                busy = self._poll()
+                if not busy:
+                    time.sleep(self._poll_s)
+            self._poll()
+        except BaseException as exc:
+            self.failure = exc      # reported by MonitorThread.check()
+            raise
 
     def _poll(self) -> bool:
         progressed = False

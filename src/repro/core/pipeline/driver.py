@@ -21,6 +21,7 @@ environment (CI runs the tier-1 suite once with
 """
 from __future__ import annotations
 
+import multiprocessing
 import os
 import pickle
 import time
@@ -166,7 +167,12 @@ def _process_pool(workers: int) -> ProcessPoolExecutor:
         for old in _PROCESS_POOLS.values():   # at most one pool alive
             old.shutdown(wait=False)
         _PROCESS_POOLS.clear()
-        ex = ProcessPoolExecutor(max_workers=workers)
+        # spawned, never forked: the caller may be a profiled job that
+        # holds the TPU runtime (and monitor threads), which a forked
+        # worker would inherit.  The workers need no jax.
+        ex = ProcessPoolExecutor(max_workers=workers,
+                                 mp_context=multiprocessing.get_context(
+                                     "spawn"))
         _PROCESS_POOLS[workers] = ex
     return ex
 

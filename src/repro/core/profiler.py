@@ -666,13 +666,21 @@ class Profiler:
     def flush(self, timeout: float = 10.0) -> bool:
         """Quiesce the monitor (all rings + trace channels drained,
         in-flight batches routed), then graft the shadow CCTs into the
-        per-thread trees.  Dispatching threads must be quiescent."""
-        ok = self._monitor.quiesce(timeout)
+        per-thread trees.  Dispatching threads must be quiescent.
+
+        Returns True.  Raises TimeoutError if the records did not drain
+        within ``timeout`` and RuntimeError if a measurement thread
+        died: either way the profile would be missing records."""
+        if not self._monitor.quiesce(timeout):
+            raise TimeoutError(
+                f"profiler records did not drain within {timeout} s")
         self._graft_shadow()
-        return ok
+        return True
 
     def write(self) -> Dict[str, str]:
-        """Writes all profiles + traces.  Returns {label: path}."""
+        """Writes all profiles + traces.  Returns {label: path}.  Raises
+        RuntimeError if a measurement thread died."""
+        self._monitor.check()
         self._graft_shadow()    # no-op when flush already ran
         out: Dict[str, str] = {}
         mods = [self._module_names[m] for m in sorted(self._modules)]

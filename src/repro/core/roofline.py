@@ -19,12 +19,8 @@ import dataclasses
 import json
 from typing import Dict, Optional
 
+from repro.core.peaks import Peaks
 from repro.core.structure import HloModule, collective_bytes, parse_hlo
-
-# TPU v5e-class constants (per chip)
-PEAK_FLOPS = 197e12       # bf16
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 5.0e10           # bytes/s per link (prompt: ~50 GB/s/link)
 
 
 @dataclasses.dataclass
@@ -41,6 +37,7 @@ class RooflineReport:
     t_collective: float
     model_flops_total: float
     bytes_per_dev: Dict[str, float]
+    peak_flops: float
 
     @property
     def dominant(self) -> str:
@@ -64,7 +61,7 @@ class RooflineReport:
     @property
     def mfu(self) -> float:
         """Roofline-model MFU: useful model flops / (chips*peak*step_time)."""
-        denom = self.chips * PEAK_FLOPS * self.step_time
+        denom = self.chips * self.peak_flops * self.step_time
         return self.model_flops_total / denom if denom else 0.0
 
     @property
@@ -94,11 +91,11 @@ class RooflineReport:
 
 
 def analyze(name: str, mesh_desc: str, chips: int, cost: Dict[str, float],
-            hlo_text: Optional[str] = None,
+            peaks: Peaks, hlo_text: Optional[str] = None,
             module: Optional[HloModule] = None,
-            model_flops_total: float = 0.0,
-            peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW,
-            ici_bw: float = ICI_BW) -> RooflineReport:
+            model_flops_total: float = 0.0) -> RooflineReport:
+    """The three roofline terms of one compiled step at ``peaks``' rates
+    (the chip the step was compiled for)."""
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     if module is None:
@@ -115,12 +112,13 @@ def analyze(name: str, mesh_desc: str, chips: int, cost: Dict[str, float],
         hlo_bytes_per_dev=nbytes,
         coll_operand_bytes=coll["operand_bytes"],
         coll_wire_bytes=coll["wire_bytes"],
-        t_compute=flops / peak_flops,
-        t_memory=nbytes / hbm_bw,
-        t_collective=coll["wire_bytes"] / ici_bw,
+        t_compute=flops / peaks.flops,
+        t_memory=nbytes / peaks.hbm_bw,
+        t_collective=coll["wire_bytes"] / peaks.ici_bw,
         model_flops_total=model_flops_total,
         bytes_per_dev={k: v for k, v in coll.items()
                        if k.startswith("operand_bytes/")},
+        peak_flops=peaks.flops,
     )
 
 

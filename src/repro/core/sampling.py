@@ -27,12 +27,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.core.peaks import Peaks, device_peaks
 from repro.core.structure import HloModule, HloOp
-
-# TPU v5e-class chip constants (also used by roofline.py)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 4.5e10              # ~bytes/s effective per link direction
 
 STALL_CLASSES = ("compute", "memory", "collective")
 
@@ -49,14 +45,14 @@ class Sample:
     leaf: int = -1           # kernel-interior leaf index (kstruct), or -1
 
 
-def op_time_model(op: HloOp) -> Dict[str, float]:
-    """Roofline time terms for one op (seconds)."""
-    tc = op.flops / PEAK_FLOPS
-    tm = op.bytes / HBM_BW
+def op_time_model(op: HloOp, peaks: Peaks) -> Dict[str, float]:
+    """Roofline time terms for one op (seconds) at ``peaks``' rates."""
+    tc = op.flops / peaks.flops
+    tm = op.bytes / peaks.hbm_bw
     tcoll = 0.0
     if op.is_collective:
         g = max(op.group_size, 1)
-        tcoll = op.bytes * 2.0 * (g - 1) / g / ICI_BW
+        tcoll = op.bytes * 2.0 * (g - 1) / g / peaks.ici_bw
     return {"compute": tc, "memory": tm, "collective": tcoll}
 
 
@@ -76,19 +72,20 @@ def op_weights(module: HloModule) -> "np.ndarray":
     ops = module.all_ops()
     kstructs = module.kernel_structures() \
         if hasattr(module, "kernel_structures") else {}
+    peaks = device_peaks()
     w = np.zeros(len(ops))
     stall = np.zeros(len(ops), np.int32)
     for i, op in enumerate(ops):
         if op.opcode in _NON_INST:
             continue
-        t = op_time_model(op)
+        t = op_time_model(op, peaks)
         ks = kstructs.get(op.index)
         if ks is not None:
             # a bound Pallas kernel parses as an opaque custom-call with
             # flops=0; its recovered interior structure supplies the
             # modeled compute/memory terms instead
-            t["compute"] = max(t["compute"], ks.total_flops / PEAK_FLOPS)
-            t["memory"] = max(t["memory"], ks.total_bytes / HBM_BW)
+            t["compute"] = max(t["compute"], ks.total_flops / peaks.flops)
+            t["memory"] = max(t["memory"], ks.total_bytes / peaks.hbm_bw)
         w[i] = max(t.values())
         stall[i] = int(np.argmax([t["compute"], t["memory"],
                                   t["collective"]]))

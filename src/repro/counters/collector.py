@@ -30,6 +30,7 @@ from typing import Dict, Iterable, Optional, Sequence
 import numpy as np
 
 from repro.core.metrics import GPU_COUNTER_METRICS
+from repro.core.peaks import device_peaks
 from repro.core.sampling import op_time_model
 from repro.core.structure import HloModule, collective_bytes
 from repro.counters.scheduler import MultiplexSchedule, build_schedule
@@ -88,6 +89,7 @@ def static_counters(module: HloModule,
         return cache[1]
 
     vec = np.zeros(_N, np.float64)
+    peaks = device_peaks()
     mults = module.comp_multipliers()
     fused = module.fused_comps()
     kstructs = module.kernel_structures() \
@@ -112,7 +114,7 @@ def static_counters(module: HloModule,
                 read_b += (op.bytes - op.out_bytes) * m
             if op.opcode not in _NON_INST:
                 inst += m
-                t = op_time_model(op)
+                t = op_time_model(op, peaks)
                 active_s += max(t.values()) * m
             ks = kstructs.get(op.index)
             if ks is not None:

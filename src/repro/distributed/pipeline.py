@@ -76,11 +76,11 @@ def pipeline_apply(layer_fn: Callable, params_stacked, x_microbatches, *,
         outs = jax.lax.psum(outs, stage_axis)
         return outs
 
-    from repro.distributed.shardmap_compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         stage_prog, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(params_stacked, x_microbatches)
 
 

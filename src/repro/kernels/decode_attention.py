@@ -8,9 +8,13 @@ scratch in VMEM — the split-KV half of "flash decoding", with the final
 merge happening in the same carry (TPU grids execute sequentially, so no
 separate reduction kernel is needed).
 
-GQA layout: one grid cell covers ALL G grouped q-heads of one kv head —
-q block (G, D) x kv block (bk, D) keeps the MXU busy with a (G x bk)
-score tile instead of G separate (1 x bk) vector products.
+GQA layout: one grid cell covers ALL kv heads of one kv block, and the
+kernel loops over them — per kv head, the q block (G, D) x kv block
+(bk, D) keeps the MXU busy with a (G x bk) score tile instead of G
+separate (1 x bk) vector products.  The cache is read through its free
+(B, Smax, Hkv*D) view, so each grid step DMAs one contiguous
+(bk, Hkv*D) slab and every block's last two dimensions are whole
+(TPU tiling), with no transpose of the cache.
 
 Length masking: positions >= ``length`` (the current cache fill) are
 masked with -inf before the online-softmax update; whole blocks beyond
@@ -23,14 +27,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, bk: int, nk: int):
-    """Grid (B, Hkv, nk); nk innermost/sequential."""
-    ki = pl.program_id(2)
+                   acc_scr, *, bk: int, nk: int, n_kv: int, d: int):
+    """Grid (B, nk); nk innermost/sequential."""
+    ki = pl.program_id(1)
     length = len_ref[0]
 
     @pl.when(ki == 0)
@@ -43,29 +48,30 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(k_lo < length)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)    # (bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # (G, bk)
-        s *= q.shape[-1] ** -0.5
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
-        m_prev = m_scr[...]                          # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)                   # (G, D)
+            k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # (bk, D)
+            v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # (bk, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (G, bk)
+            s *= d ** -0.5
+            kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos < length, s, NEG_INF)
+            m_prev = m_scr[h]                                    # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h] = l_scr[h] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_decode_fwd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -81,34 +87,33 @@ def flash_decode_fwd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     assert Smax % bk == 0, (Smax, bk)
     nk = Smax // bk
     qh = q.reshape(B, Hkv, G, D)
+    kf = k_cache.reshape(B, Smax, Hkv * D)
+    vf = v_cache.reshape(B, Smax, Hkv * D)
     length = jnp.asarray(length, jnp.int32).reshape(1)
 
-    kern = functools.partial(_decode_kernel, bk=bk, nk=nk)
+    kern = functools.partial(_decode_kernel, bk=bk, nk=nk, n_kv=Hkv, d=D)
     out = pl.pallas_call(
         kern,
-        grid=(B, Hkv, nk),
+        grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (0,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, ki: (b, ki, h, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, Hkv, G, D), lambda b, ki: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv * D), lambda b, ki: (b, ki, 0)),
+            pl.BlockSpec((1, bk, Hkv * D), lambda b, ki: (b, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, D), lambda b, ki: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[_vmem((G, 1)), _vmem((G, 1)), _vmem((G, D))],
+        scratch_shapes=[pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                        pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                        pltpu.VMEM((Hkv, G, D), jnp.float32)],
         interpret=interpret,
-    )(length, qh, k_cache, v_cache)
+    )(length, qh, kf, vf)
     return out.reshape(B, H, D)
 
 
-def _vmem(shape):
-    import jax.experimental.pallas.tpu as pltpu
-    return pltpu.VMEM(shape, jnp.float32)
-
-
-# kstruct annotation: grid (B, Hkv, nk); ki over kv-cache blocks is the
+# kstruct annotation: grid (B, nk); ki over kv-cache blocks is the
 # sequential split-KV loop carrying the online-softmax scratch
-KSTRUCT_GRID_LOOPS = {2: "kv_blocks"}
+KSTRUCT_GRID_LOOPS = {1: "kv_blocks"}
 
 
 def kernel_structure(*, block_kv: int = 512):

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-- interpret mode is selected automatically off-TPU (this container is
-  CPU-only: kernels execute via the Pallas interpreter, which runs the
-  kernel body in Python and validates the BlockSpec tiling/index maps).
+- on a TPU the kernels are compiled by Mosaic; on the CPU backend they
+  run in interpret mode (the Pallas interpreter runs the kernel body in
+  Python and validates the BlockSpec tiling/index maps).  Any other
+  backend is an error: the kernels are written for the TPU alone.
 - both wrappers are differentiable: forward = Pallas kernel, backward =
   O(S)-memory block-recompute VJP expressed in pure jnp (the flash trick;
   on TPU the backward would be a second Pallas kernel with the same
@@ -22,7 +23,14 @@ from repro.kernels import ssm_scan as _ss
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU, "
+        f"not on {backend!r}")
 
 
 # ===========================================================================

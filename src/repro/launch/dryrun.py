@@ -26,6 +26,7 @@ import jax
 
 from repro.configs import SHAPES, get_config, list_configs, shape_applicable
 from repro.core import roofline as roof_mod
+from repro.core.peaks import peaks_for
 from repro.core.structure import parse_hlo
 from repro.distributed import sharding as shard_mod
 from repro.launch import mesh as mesh_mod
@@ -35,6 +36,8 @@ from repro.models.transformer import ModelOptions
 from repro.optim.adamw import OptConfig
 
 HBM_PER_CHIP = 16 * 1024 ** 3   # v5e: 16 GiB
+# the chip the dry run sizes its meshes for (compiled on host devices)
+TARGET = peaks_for("TPU v5 lite")
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
@@ -102,7 +105,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         hlo_text = compiled.as_text()
         module = parse_hlo(hlo_text, name=label)
         report = roof_mod.analyze(
-            label, mesh_desc, chips, cost, module=module,
+            label, mesh_desc, chips, cost, TARGET, module=module,
             model_flops_total=roof_mod.model_flops(cfg, shape))
         per_dev = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                    + mem.output_size_in_bytes - mem.alias_size_in_bytes)

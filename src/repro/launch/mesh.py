@@ -9,13 +9,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh``: ``jax.sharding.AxisType`` landed
-    after 0.4.x; older jax infers Auto axes when the kwarg is omitted."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by the
+    compiler, not spelled out per op)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.window import DECODE, PREFILL
 
@@ -168,6 +169,7 @@ def main(argv=None):
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--profile-dir", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -30,6 +30,7 @@ from repro.distributed import sharding as shard_mod
 from repro.ft import RestartPolicy, StragglerWatchdog
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw
 
@@ -134,6 +135,13 @@ def train(cfg: ModelConfig, shape: ShapeConfig, *, n_steps: int = 20,
     return params, history, paths
 
 
+def seq_options(seq: int) -> T.ModelOptions:
+    """Attention/SSM/loss chunking for training at sequence length
+    ``seq`` (chunks of at most 256, 128 for the SSM scan)."""
+    return T.ModelOptions(q_chunk=min(256, seq), kv_chunk=min(256, seq),
+                          ssm_chunk=min(128, seq), loss_chunk=min(256, seq))
+
+
 class _nullcontext:
     def __enter__(self):
         return self
@@ -157,21 +165,18 @@ def main(argv=None):
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     shape = ShapeConfig("custom", args.seq, args.batch, "train")
-    opts = T.ModelOptions(q_chunk=min(256, args.seq),
-                          kv_chunk=min(256, args.seq),
-                          ssm_chunk=min(128, args.seq),
-                          loss_chunk=min(256, args.seq))
     t0 = time.monotonic()
     _, history, paths = train(
         cfg, shape, n_steps=args.steps, ckpt_dir=args.ckpt_dir,
         ckpt_every=args.ckpt_every, profile_dir=args.profile_dir,
-        opts=opts, grad_compression=args.grad_compression, seed=args.seed,
-        resume=args.resume)
+        opts=seq_options(args.seq), grad_compression=args.grad_compression,
+        seed=args.seed, resume=args.resume)
     print(f"done in {time.monotonic() - t0:.1f}s; "
           f"final loss {history[-1]['loss']:.4f}")
     if paths:

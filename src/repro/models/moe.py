@@ -185,11 +185,11 @@ def moe_ffn(params, x, *, n_experts: int, top_k: int,
         _local_moe, n_experts=n_experts, top_k=top_k, capacity=cap,
         e_loc=e_loc, model_axis=mesh_args.model_axis, fsdp_axis=fsdp,
         dp_axes=tuple(mesh_args.dp_axes), weight_mode=mode)
-    from repro.distributed.shardmap_compat import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(tuple(mesh_args.dp_axes), None), P(None, None),
                   w_d, w_d, w_f),
         out_specs=(P(tuple(mesh_args.dp_axes), None), P()),
+        check_vma=False,
     )(x2, params["router"], params["w1"], params["w3"], params["w2"])
     return y.reshape(B, S, d), aux

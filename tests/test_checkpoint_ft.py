@@ -38,6 +38,21 @@ def test_async_save_and_wait(tmp_path):
     assert mgr.latest_step() == 1
 
 
+def test_async_save_failure_raises_at_wait(tmp_path, monkeypatch):
+    """A background save that fails must not pass for a saved step."""
+    mgr = CheckpointManager(str(tmp_path))
+
+    def full_disk(*a, **k):
+        raise OSError("no space left on device")
+    monkeypatch.setattr(np, "save", full_disk)
+    mgr.save(1, tree(), block=False)
+    with pytest.raises(RuntimeError) as err:
+        mgr.wait()
+    assert isinstance(err.value.__cause__, OSError)
+    assert mgr.latest_step() is None
+    mgr.wait()                  # the failure is reported once
+
+
 def test_atomicity_tmp_never_visible(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     mgr.save(3, tree())

@@ -152,3 +152,15 @@ def test_ssm_kernel_in_mamba_forward():
                               chunk=32, use_kernel=False)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yj),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_kernels_refuse_backends_they_are_not_written_for(monkeypatch):
+    """Compiled on a TPU, interpreted on the CPU, and an error anywhere
+    else — never a silent interpret-mode run on an accelerator."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._use_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._use_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops._use_interpret()

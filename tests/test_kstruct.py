@@ -231,6 +231,20 @@ def test_decode_and_ssm_interiors(recovered):
     assert len(dots) >= 3
 
 
+def test_source_info_failure_propagates(monkeypatch, recovered):
+    """A failing source-info lookup raises instead of recovering a
+    kernel of one leaf with no file — the failure that once hid behind
+    an ``except Exception: return []``."""
+    from jax._src import source_info_util
+    from repro.kernels import flash_attention
+
+    def boom(traceback):
+        raise AttributeError("source info unavailable")
+    monkeypatch.setattr(source_info_util, "user_frames", boom)
+    with pytest.raises(AttributeError, match="source info unavailable"):
+        flash_attention.kernel_structure()
+
+
 def test_recovery_is_deterministic(recovered):
     from repro.kernels import flash_attention
     a = flash_attention.kernel_structure()

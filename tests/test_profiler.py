@@ -72,11 +72,16 @@ def test_multithreaded_dispatch(tmp_path, compiled):
     prof = Profiler(str(tmp_path), tracing=False, rng_seed=0, unwind=False)
     mid = prof.register_module("f", comp.as_text())
     N, K = 4, 8
+    # all workers stay alive until each has dispatched: a thread that
+    # exits early can hand its ident to a later one, merging two
+    # threads' profiles into one
+    done = threading.Barrier(N)
 
     def worker(i):
         for _ in range(K):
             with prof.dispatch("kernel", "f", stream=i, module_id=mid):
                 jax.block_until_ready(comp(x))
+        done.wait(timeout=60)
 
     with prof:
         ts = [threading.Thread(target=worker, args=(i,)) for i in range(N)]
@@ -141,3 +146,31 @@ def test_flush_quiesces(tmp_path, compiled):
         jax.block_until_ready(comp(x))
     assert prof.flush(timeout=20)
     prof.stop()
+
+
+def test_flush_raises_when_monitor_dies(tmp_path):
+    """A monitor that dies mid-run must not leave a silently partial
+    profile behind: flush and write both raise, chained to the cause."""
+    prof = Profiler(str(tmp_path), tracing=True, unwind=False)
+
+    def broken(tid, payloads, lane):
+        raise ValueError("attribution failed")
+    prof._monitor._handler = broken
+    prof.start()
+    with prof.dispatch("kernel", "f", stream=0, duration_ns=1000):
+        pass
+    with pytest.raises(RuntimeError) as err:
+        prof.flush(timeout=30)
+    assert isinstance(err.value.__cause__, ValueError)
+    with pytest.raises(RuntimeError):
+        prof.write()
+    prof.stop()
+
+
+def test_flush_raises_when_records_do_not_drain(tmp_path):
+    prof = Profiler(str(tmp_path), tracing=True, unwind=False)
+    # never started: no monitor drains the ring
+    with prof.dispatch("kernel", "f", stream=0, duration_ns=1000):
+        pass
+    with pytest.raises(TimeoutError):
+        prof.flush(timeout=0.05)

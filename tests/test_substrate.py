@@ -138,17 +138,13 @@ def test_error_feedback_converges():
 
 
 def test_compressed_psum_matches_plain():
-    try:
-        from jax import shard_map
-    except ImportError:   # moved out of experimental in newer jax
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_test_mesh
     mesh = make_test_mesh((1, 1))
     x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 256)),
                     jnp.float32)
-    f = shard_map(lambda v: comp.compressed_psum(v, "data"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda v: comp.compressed_psum(v, "data"), mesh=mesh,
+                      in_specs=P(), out_specs=P())
     np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x), rtol=2e-2,
                                atol=2e-2)
 

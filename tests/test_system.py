@@ -135,13 +135,43 @@ def test_model_flops_convention():
     assert dc == pytest.approx(2 * n * 128)
 
 
+def test_peak_table_is_keyed_by_device_kind():
+    from repro.core.peaks import device_peaks, peaks_for
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v9"):
+        peaks_for("TPU v9")       # an unknown chip is an error
+    assert device_peaks() is v5e  # the CPU backend stands in for a v5e
+
+
 def test_roofline_report_terms():
+    from repro.core.peaks import peaks_for
     from repro.core.roofline import analyze
     hlo = "HloModule m\n\nENTRY %main (x: f32[8]) -> f32[8] {\n" \
           "  ROOT %x = f32[8]{0} parameter(0)\n}\n"
     rep = analyze("t", "mesh", 4, {"flops": 197e12, "bytes accessed": 0.0},
-                  hlo_text=hlo, model_flops_total=4 * 197e12)
+                  peaks_for("TPU v5 lite"), hlo_text=hlo,
+                  model_flops_total=4 * 197e12)
     assert rep.t_compute == pytest.approx(1.0)
     assert rep.dominant == "compute"
     assert rep.mfu == pytest.approx(1.0)
     assert rep.useful_ratio == pytest.approx(1.0)
+
+
+def test_compile_cache_follows_the_environment(monkeypatch):
+    """Placed from outside when JAX_COMPILATION_CACHE_DIR is set (and
+    then nothing is set in code), else a fixed directory in the
+    checkout."""
+    from repro.launch import compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "placed-outside")
+    assert compile_cache.enable_compile_cache() == "placed-outside"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert compile_cache.enable_compile_cache() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

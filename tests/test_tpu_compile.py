@@ -1,0 +1,97 @@
+"""The chip's own compiler accepts the main path (no chip needed).
+
+Interpret mode validates BlockSpec index maps but not the TPU's tiling
+rules, VMEM limits or device memory, so every kernel used to pass its
+CPU tests while the chip's compiler refused two of them.  Here the TPU
+compiler, installed with libtpu, compiles for a *described* v5e chip:
+
+- the three Pallas kernels at the widths the deployments use, each
+  lowering to a ``tpu_custom_call``;
+- qwen2-1.5b's prefill step at published widths, whose compiled
+  footprint must fit one chip's 16 GiB.
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu, and under pytest-xdist each worker imports
+every test file.  Compiles run in the test's own process for the same
+reason.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a persistent-cache entry compiled for a described chip cannot be
+    # read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _flash(spec):
+    from repro.kernels.flash_attention import flash_attention_fwd
+    # qwen2-1.5b prefill: 12 q heads, 2 kv heads, head_dim 128
+    return (functools.partial(flash_attention_fwd, causal=True),
+            spec((4, 2048, 12, 128)), spec((4, 2048, 2, 128)),
+            spec((4, 2048, 2, 128)))
+
+
+def _decode(spec):
+    from repro.kernels.decode_attention import flash_decode_fwd
+    return (flash_decode_fwd, spec((8, 12, 128)), spec((8, 4096, 2, 128)),
+            spec((8, 4096, 2, 128)), spec((), jnp.int32))
+
+
+def _ssm(spec):
+    from repro.kernels.ssm_scan import ssm_scan_fwd
+    # hymba-1.5b's mamba heads: 25 heads of 64, state 16, chunk 256
+    return (functools.partial(ssm_scan_fwd, chunk=256),
+            spec((2, 2048, 25, 64)), spec((2, 2048, 25), jnp.float32),
+            spec((2, 2048, 16)), spec((2, 2048, 16)))
+
+
+@pytest.mark.parametrize("build", [_flash, _decode, _ssm],
+                         ids=["flash_attention", "flash_decode", "ssm_scan"])
+def test_kernel_compiles_for_v5e(one_chip, build):
+    fn, *args = build(functools.partial(_spec, one_chip))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_prefill_fits_one_chip(one_chip):
+    from repro.configs import get_config
+    from repro.launch import steps as steps_mod
+    from repro.models import transformer as T
+    cfg = get_config("qwen2-1.5b")
+    batch, prompt = 4, 512
+    opts = T.ModelOptions(q_chunk=256, kv_chunk=256, loss_chunk=256)
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: T.init_params(k, cfg),
+                       jax.random.PRNGKey(0)))
+    tokens = _spec(one_chip, (batch, prompt), jnp.int32)
+    step = jax.jit(steps_mod.make_prefill_step(cfg, None, opts))
+    mem = step.lower(params, {"tokens": tokens}).compile().memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # the bf16 parameters alone are ~3.55 GB
+    assert 3.0e9 < mem.argument_size_in_bytes < total < HBM_BYTES
