@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from repro.core.channels import RingSet, SpscQueue
 from repro.core.cct import CCTNode
+from repro.core.spans import span
 
 OP = 0
 ACTIVITY = 1
@@ -102,7 +103,7 @@ class MonitorThread:
         self._trace_threads: List[TracingThread] = []
         self._n_tracing = max(1, n_tracing_threads)
         self.stats = {"ops": 0, "activities": 0, "routed": 0,
-                      "counter_records": 0, "drains": 0}
+                      "counter_records": 0}
         # (stream, [(A, P), ...]) -> None, one call per drained batch
         self.trace_sink: Optional[Callable] = None
         # the exception that ended the monitor loop, if one did
@@ -198,18 +199,21 @@ class MonitorThread:
                 continue
             busy = True
             payloads, lane, _epoch = got
-            acts, hstats = self._handler(tid, payloads, lane)
-            for k, v in hstats.items():
-                stats[k] = stats.get(k, 0) + v
-            stats["drains"] += 1
-            if acts:
-                stats["routed"] += len(acts)
-                if self._tracing:
-                    traced: Dict[int, List[tuple]] = {}
-                    for pair in acts:
-                        traced.setdefault(pair[0].stream, []).append(pair)
-                    for stream, batch in traced.items():
-                        self._push_all(self._trace_queue(stream), batch)
+            with span("monitor.drain", records=len(payloads)) as live:
+                acts, hstats = self._handler(tid, payloads, lane)
+                for k, v in hstats.items():
+                    stats[k] = stats.get(k, 0) + v
+                if acts:
+                    stats["routed"] += len(acts)
+                    if self._tracing:
+                        traced: Dict[int, List[tuple]] = {}
+                        for pair in acts:
+                            traced.setdefault(pair[0].stream,
+                                              []).append(pair)
+                        for stream, batch in traced.items():
+                            self._push_all(self._trace_queue(stream), batch)
+                if live is not None:
+                    live.set_metadata(activities=len(acts))
             self._routing = False
         return busy
 

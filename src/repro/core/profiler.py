@@ -47,6 +47,7 @@ from repro.core.metrics import MetricRegistry, default_registry
 from repro.core.monitor import (ACTIVITY, OP, GpuActivity, GpuOperation,
                                 MonitorThread)
 from repro.core.profmt import write_profile
+from repro.core.spans import span
 from repro.core.structure import HloModule, parse_hlo
 from repro.core.trace import TraceWriter, pack_dispatch_ctx
 
@@ -490,7 +491,8 @@ class Profiler:
                 else:
                     rng = (keyed.stream(st.index, seq)
                            if keyed is not None else None)
-                    samples = sampling.draw_samples(mod, n_budget, rng)
+                    with span("sampling.draw", samples=n_budget):
+                        samples = sampling.draw_samples(mod, n_budget, rng)
                     k = 0
                     for s in samples:
                         k += s.count

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
+from repro.core.spans import span
 from repro.launch import steps as steps_mod
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
@@ -100,43 +101,59 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
         lo, hi = bi * batch, min(bi * batch + batch, n_requests)
         rid = f"{rid_prefix}r{lo}" if hi - lo <= 1 \
             else f"{rid_prefix}r{lo}-r{hi - 1}"
-        toks = jnp.asarray(rng.integers(0, cfg.vocab, (batch, prompt_len),
-                                        np.int32))
-        batch_in = {"tokens": toks}
+        with span("serve.inputs"):
+            toks = jnp.asarray(rng.integers(0, cfg.vocab,
+                                            (batch, prompt_len), np.int32))
+            batch_in = {"tokens": toks}
         # --- prefill ------------------------------------------------------
         with _maybe_window(serving, rid, PREFILL, batch * prompt_len):
             if prof is not None:
-                with prof.dispatch("kernel", "prefill", stream=0,
-                                   module_id=mid_p):
-                    logits, cache = prefill_fn(params, batch_in)
-                    jax.block_until_ready(logits)
+                with span("serve.dispatch", phase=PREFILL), \
+                        prof.dispatch("kernel", "prefill", stream=0,
+                                      module_id=mid_p):
+                    with span("serve.enqueue", phase=PREFILL):
+                        logits, cache = prefill_fn(params, batch_in)
+                    with span("serve.sync", phase=PREFILL):
+                        jax.block_until_ready(logits)
             else:
                 logits, cache = prefill_fn(params, batch_in)
         # cache is sized prompt_len by prefill; decode needs max_len slots
-        cache = _grow_cache(cfg, cache, batch, max_len, prompt_len)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        gen = [tok]
+        with span("serve.grow_cache"):
+            cache = _grow_cache(cfg, cache, batch, max_len, prompt_len)
+        with span("serve.next_token"):
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            gen = [tok]
+            pos = jnp.int32(prompt_len)
         # --- decode ---------------------------------------------------------
         for t in range(gen_len - 1):
-            pos = jnp.int32(prompt_len + t)
             with _maybe_window(serving, rid, DECODE, batch):
                 if prof is not None:
-                    with prof.dispatch("kernel", "decode_step", stream=0,
-                                       module_id=mid_d):
-                        logits, cache = decode_fn(params, cache, pos,
-                                                  token=tok)
-                        jax.block_until_ready(logits)
+                    # serve.dispatch's self time is the profiler's own
+                    # enter and exit.  The step's result is bound before
+                    # the sync, so the previous KV cache is released
+                    # while the program runs, not after it
+                    with span("serve.dispatch", phase=DECODE), \
+                            prof.dispatch("kernel", "decode_step", stream=0,
+                                          module_id=mid_d):
+                        with span("serve.enqueue", phase=DECODE):
+                            logits, cache = decode_fn(params, cache, pos,
+                                                      token=tok)
+                        with span("serve.sync", phase=DECODE):
+                            jax.block_until_ready(logits)
                     if redundant_sync:
                         # §8.4.1: a sync with no kernel between it and the
                         # previous sync — found by diff = sync - kernels
-                        with prof.dispatch("sync", "device_sync", stream=0):
-                            jax.block_until_ready(logits)
-                        with prof.dispatch("sync", "device_sync", stream=0):
-                            jax.block_until_ready(logits)
+                        for _ in range(2):
+                            with span("serve.dispatch", phase=DECODE), \
+                                    prof.dispatch("sync", "device_sync",
+                                                  stream=0):
+                                jax.block_until_ready(logits)
                 else:
                     logits, cache = decode_fn(params, cache, pos, token=tok)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            gen.append(tok)
+            with span("serve.next_token"):
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                gen.append(tok)
+                pos = jnp.int32(prompt_len + t + 1)
         outs.append(jnp.stack(gen, axis=1))
     paths = None
     if own_prof:

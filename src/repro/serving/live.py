@@ -29,6 +29,7 @@ import time
 from typing import Callable, Optional, Union
 
 from repro.core.profiler import Profiler
+from repro.core.spans import span
 from repro.serving.governor import GovernorConfig, OverheadGovernor
 from repro.serving.stats import ServingStats
 from repro.serving.telemetry import TelemetryExporter
@@ -88,21 +89,32 @@ class ServingProfiler:
         run a governor observation, export telemetry when due.  Called
         automatically when a ``request()`` window closes; long-running
         loops without windows may call it directly."""
-        if self.producer is not None:
-            poll = getattr(self.producer, "poll_backpressure", None)
-            if poll is not None:
-                poll()
+        with span("serving.tick") as live:
+            if self.producer is not None:
+                poll = getattr(self.producer, "poll_backpressure", None)
+                if poll is not None:
+                    poll()
+                if self.governor is not None:
+                    self.governor.note_backpressure(self.producer.throttled)
             if self.governor is not None:
-                self.governor.note_backpressure(self.producer.throttled)
-        if self.governor is not None:
-            # SLO feed: the worst current rolling p99 across phases (0.0
-            # — no requests in the window yet — means no signal)
-            p99 = max(self.stats.percentile_ms(PREFILL, 99),
-                      self.stats.percentile_ms(DECODE, 99))
-            self.governor.observe(p99_ms=p99 if p99 > 0 else None)
-        if self.exporter is not None and \
-                self.wall() - self._last_export >= self.export_every_s:
-            self.export_now()
+                # SLO feed: the worst current rolling p99 across phases
+                # (0.0 — no requests in the window yet — means no signal)
+                with span("serving.p99"):
+                    p99 = max(self.stats.percentile_ms(PREFILL, 99),
+                              self.stats.percentile_ms(DECODE, 99))
+                with span("governor.observe"):
+                    self.governor.observe(p99_ms=p99 if p99 > 0 else None)
+            if self.exporter is not None and \
+                    self.wall() - self._last_export >= self.export_every_s:
+                with span("telemetry.export"):
+                    self.export_now()
+            if live is not None:
+                # the profiler's cumulative self-accounting and the rung
+                # the governor left the job on, read by the trace
+                args = self.profiler.overhead_counters()
+                if self.governor is not None:
+                    args["level"] = self.governor.level
+                live.set_metadata(**args)
 
     def export_now(self) -> Optional[str]:
         """Export one telemetry epoch immediately; returns the shard id
@@ -133,7 +145,13 @@ class _TrackedWindow(RequestWindow):
         self._owner = owner
         self.tokens = tokens
 
+    def __enter__(self) -> "_TrackedWindow":
+        with span("serving.open", phase=self.phase):
+            return super().__enter__()
+
     def __exit__(self, *exc) -> None:
-        super().__exit__(*exc)
-        self._owner.stats.record_window(self, tokens=self.tokens)
-        self._owner.tick()
+        with span("serving.close", phase=self.phase):
+            super().__exit__(*exc)
+            with span("serving.stats"):
+                self._owner.stats.record_window(self, tokens=self.tokens)
+            self._owner.tick()

@@ -33,17 +33,12 @@ class ServingStats:
         self.window_s = float(window_s)
         self.clock = clock
         self._rows: Deque[_Row] = collections.deque(maxlen=maxlen)
-        self.total_requests = 0
-        self.total_tokens = 0
 
     # -- ingestion ----------------------------------------------------------
     def record(self, request_id, phase: str, duration_ns: int,
                tokens: int = 0) -> None:
         self._rows.append((self.clock(), str(request_id), str(phase),
                            int(duration_ns), int(tokens)))
-        if phase == PREFILL:
-            self.total_requests += 1
-        self.total_tokens += int(tokens)
 
     def record_window(self, window, tokens: int = 0) -> None:
         """Record a closed ``RequestWindow`` directly."""

@@ -279,9 +279,13 @@ def test_governor_converges_under_real_load(tmp_path):
                     _spin(50_000)
     assert sp.governor.level == FLOOR
     assert sp.governor.throttle_downs >= FLOOR
-    # generous: dispatch cost against 2ms spins sits far below 500%
+    # generous: dispatch cost against 2ms spins sits far below 500%.
+    # The SLO shed is off here (it has tests of its own): it reacts to
+    # the wall-clock p99 of the spins, which a loaded host stretches
     sp2 = ServingProfiler(str(tmp_path / "loose"),
-                          governor=GovernorConfig(budget=5.0, interval=4),
+                          governor=GovernorConfig(
+                              budget=5.0, interval=4,
+                              slo_degradation=float("inf")),
                           sample_rate_hz=1e6)
     with sp2:
         for i in range(16):
@@ -289,6 +293,7 @@ def test_governor_converges_under_real_load(tmp_path):
                 with sp2.profiler.dispatch("kernel", "step", stream=0):
                     _spin(2_000_000)
     assert sp2.governor.level == 0 and sp2.governor.throttle_downs == 0
+    assert sp2.governor.slo_sheds == 0
 
 
 # ---------------------------------------------------------------------------
