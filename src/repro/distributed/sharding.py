@@ -237,15 +237,15 @@ def cache_shardings(cache_shape, cfg: ModelConfig, plan: ShardingPlan,
     def spec(path, leaf):
         name = _tree_path_str(path).split("/")[-1]
         nd = len(leaf.shape)
-        if name in ("k", "v") and nd == 5:     # (Pd, B, S, Hkv, Dh)
-            hkv, smax = leaf.shape[3], leaf.shape[2]
+        if name in ("k", "v") and nd == 5:     # (Pd, B, Hkv, S, Dh)
+            hkv, smax = leaf.shape[2], leaf.shape[3]
             if kv_seq_axis and smax % sizes.get(kv_seq_axis, 1) == 0:
-                s = P(None, dp, kv_seq_axis, None, None)
+                s = P(None, dp, None, kv_seq_axis, None)
             elif m and hkv % ms == 0:
-                s = P(None, dp, None, m, None)
+                s = P(None, dp, m, None, None)
             elif m and smax % ms == 0:
                 # flash-decode style: shard cache sequence over model
-                s = P(None, dp, m, None, None)
+                s = P(None, dp, None, m, None)
             else:
                 s = P(None, dp, None, None, None)
         elif name == "ssm" and nd == 5:        # (Pd, B, nh, hd, st)

@@ -61,7 +61,9 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
     max_len = prompt_len + gen_len
 
     prefill_fn = jax.jit(steps_mod.make_prefill_step(cfg, None, opts))
-    decode_fn = jax.jit(steps_mod.make_decode_step(cfg, None, opts))
+    # the cache is donated: each step writes its new K/V rows in place
+    decode_fn = jax.jit(steps_mod.make_decode_step(cfg, None, opts),
+                        donate_argnums=(1,))
 
     if serving is not None and profile_dir:
         raise ValueError("pass either serving= or profile_dir=, not both")
@@ -82,7 +84,8 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
     cache = _grow_cache(cfg, cache, batch, max_len, prompt_len)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     pos0 = jnp.int32(prompt_len)
-    warm_logits, _ = decode_fn(params, cache, pos0, token=tok)
+    # the warm-up donates ``cache``: lower from the one it returns
+    warm_logits, cache = decode_fn(params, cache, pos0, token=tok)
     jax.block_until_ready(warm_logits)
     mid_p = mid_d = None
     if prof is not None:
@@ -129,9 +132,9 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
             with _maybe_window(serving, rid, DECODE, batch):
                 if prof is not None:
                     # serve.dispatch's self time is the profiler's own
-                    # enter and exit.  The step's result is bound before
-                    # the sync, so the previous KV cache is released
-                    # while the program runs, not after it
+                    # enter and exit.  The step takes the KV cache
+                    # donated and returns it updated in place: nothing
+                    # of the cache is copied or released per step
                     with span("serve.dispatch", phase=DECODE), \
                             prof.dispatch("kernel", "decode_step", stream=0,
                                           module_id=mid_d):
@@ -168,10 +171,10 @@ def _grow_cache(cfg, cache, batch, max_len, cur_len):
     def grow(path, leaf):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if name in ("k", "v") and leaf.ndim == 5 and \
-                leaf.shape[2] == cur_len:
-            pad = jnp.zeros(leaf.shape[:2] + (max_len - cur_len,)
-                            + leaf.shape[3:], leaf.dtype)
-            return jnp.concatenate([leaf, pad], axis=2)
+                leaf.shape[3] == cur_len:
+            pad = jnp.zeros(leaf.shape[:3] + (max_len - cur_len,)
+                            + leaf.shape[4:], leaf.dtype)
+            return jnp.concatenate([leaf, pad], axis=3)
         return leaf
     return jax.tree_util.tree_map_with_path(grow, cache)
 

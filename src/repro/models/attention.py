@@ -408,22 +408,45 @@ def swa_attention(q, k, v, window: int, chunk: int = 256) -> jax.Array:
 def decode_attention(q, k_cache, v_cache, length) -> jax.Array:
     """q: (B,H,D); caches: (B,Smax,Hkv,D); length: scalar valid length.
     Returns (B,H,D)."""
+    valid = jnp.arange(k_cache.shape[1]) < length
+    return _decode_softmax(q, k_cache.swapaxes(1, 2),
+                           v_cache.swapaxes(1, 2), valid)
+
+
+def _decode_softmax(q, k_cache, v_cache, valid, k_new=None, v_new=None):
+    """One query row per sequence against the head-major cache slots
+    (B,Hkv,Smax,D) where ``valid`` (Smax,) holds and, when given, the new
+    token's own key and value (B,Hkv,D) as one more column of the same
+    softmax.  Returns (B,H,D)."""
     B, H, D = q.shape
-    Hkv = k_cache.shape[2]
+    Hkv = k_cache.shape[1]
     G = H // Hkv
     with jax.named_scope("decode_attention"):
         qr = q.reshape(B, Hkv, G, D)
         scale = D ** -0.5
-        s = jnp.einsum("bhgd,bshd->bhgs", qr, k_cache,
+        s = jnp.einsum("bhgd,bhsd->bhgs", qr, k_cache,
                        preferred_element_type=jnp.float32) * scale
-        valid = jnp.arange(k_cache.shape[1]) < length
         s = jnp.where(valid[None, None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
+        m = s.max(axis=-1)
+        if k_new is not None:
+            s_new = jnp.einsum("bhgd,bhd->bhg", qr, k_new,
+                               preferred_element_type=jnp.float32) * scale
+            m = jnp.maximum(m, s_new)
+        e = jnp.exp(s - m[..., None])
+        denom = e.sum(axis=-1)
+        if k_new is not None:
+            e_new = jnp.exp(s_new - m)
+            denom = denom + e_new
+        p = e / denom[..., None]
         # accumulate in fp32 WITHOUT materializing an fp32 copy of the
-        # (B, Smax, Hkv, D) cache — the explicit astype was 1.6 GB/layer of
+        # (B, Hkv, Smax, D) cache — the explicit astype was 1.6 GB/layer of
         # pure convert traffic on llama4 decode_32k (§Perf B2)
-        out = jnp.einsum("bhgs,bshd->bhgd", p.astype(v_cache.dtype), v_cache,
+        out = jnp.einsum("bhgs,bhsd->bhgd", p.astype(v_cache.dtype), v_cache,
                          preferred_element_type=jnp.float32)
+        if v_new is not None:
+            p_new = (e_new / denom).astype(v_new.dtype)
+            out = out + jnp.einsum("bhg,bhd->bhgd", p_new, v_new,
+                                   preferred_element_type=jnp.float32)
     return out.reshape(B, H, D).astype(q.dtype)
 
 
@@ -431,59 +454,43 @@ def attention_block(params, x, positions, cfg, *, layer_window: int = 0,
                     kv_cache: Optional[Tuple] = None,
                     cache_pos=None, q_chunk: int = 512, kv_chunk: int = 512,
                     schedule: str = "dense", use_kernel: bool = False):
-    """Full attention sub-block.  Returns (y, new_kv_cache_entry).
+    """Full attention sub-block.  Returns (y, new_kv_rows).
 
     kv_cache: None for training; (k_cache, v_cache) of shape
-    (B, Smax, Hkv, D) for serving.  For SWA layers the cache is a ring
-    buffer of Smax == window slots.  cache_pos: absolute position of x[0].
+    (B, Hkv, Smax, D) for a decode step (S == 1) at absolute position
+    cache_pos.  Head-major, so that a layer's cache sliced out of the
+    stack feeds the score and value matmuls unmaterialized.  Slot
+    ``cache_pos % Smax`` is the new token's: a ring buffer for SWA layers
+    (Smax == window), plain for the others (cache_pos < Smax).  The cache
+    is only read: the new token attends to the slots before it that it
+    does not overwrite, and to its own key and value.  new_kv_rows are
+    those, (B, Hkv, 1, D) each in the cache's dtype, for the caller to
+    write at that slot.
     """
-    B, S, d = x.shape
     q, k, v = project_qkv(params, x, cfg, positions)
-    new_cache = None
+    new_rows = None
     if kv_cache is not None:
         k_cache, v_cache = kv_cache
-        smax = k_cache.shape[1]
-        if layer_window:
-            # ring buffer: slot = absolute position mod window.  S == 1
-            # (decode) inserts one slot; prefill with S % window == 0 fills
-            # the ring exactly with the last `window` tokens.
-            if S == 1:
-                slot = jnp.asarray(cache_pos) % smax
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k.astype(k_cache.dtype), slot, 1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v.astype(v_cache.dtype), slot, 1)
-            else:
-                k_cache = k[:, -smax:].astype(k_cache.dtype)
-                v_cache = v[:, -smax:].astype(v_cache.dtype)
-        else:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.astype(k_cache.dtype), cache_pos, 1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.astype(v_cache.dtype), cache_pos, 1)
-        new_cache = (k_cache, v_cache)
-        if S == 1:  # decode
-            length = jnp.minimum(jnp.asarray(cache_pos) + 1, smax) \
-                if layer_window else jnp.asarray(cache_pos) + 1
-            out = decode_attention(q[:, 0], k_cache, v_cache, length)[:, None]
-        else:       # prefill
-            if layer_window:
-                out = swa_attention(q, k, v, layer_window)
-            else:
-                out = chunked_attention(
-                    q, k_cache, v_cache, q_chunk=q_chunk, kv_chunk=kv_chunk,
-                    q_offset=cache_pos, window=0, schedule=schedule)
+        assert x.shape[1] == 1, "a KV cache is only read by a decode step"
+        smax = k_cache.shape[2]
+        pos = jnp.asarray(cache_pos)
+        slots = jnp.arange(smax)
+        valid = (slots < pos) & (slots != pos % smax)
+        k_new = k[:, 0].astype(k_cache.dtype)
+        v_new = v[:, 0].astype(v_cache.dtype)
+        out = _decode_softmax(q[:, 0], k_cache, v_cache, valid,
+                              k_new, v_new)[:, None]
+        new_rows = (k_new[:, :, None], v_new[:, :, None])
+    elif use_kernel:
+        from repro.kernels import ops as kernel_ops
+        out = kernel_ops.flash_attention(q, k, v, causal=True,
+                                         window=layer_window)
     else:
-        if use_kernel:
-            from repro.kernels import ops as kernel_ops
-            out = kernel_ops.flash_attention(q, k, v, causal=True,
-                                             window=layer_window)
-        else:
-            # training: the flash VJP handles the window mask (banded SWA
-            # is forward-only; its scan backward stores O(nq*nk) blocks)
-            out = chunked_attention(q, k, v, q_chunk=q_chunk,
-                                    kv_chunk=kv_chunk, window=layer_window,
-                                    schedule=schedule)
+        # training: the flash VJP handles the window mask (banded SWA
+        # is forward-only; its scan backward stores O(nq*nk) blocks)
+        out = chunked_attention(q, k, v, q_chunk=q_chunk,
+                                kv_chunk=kv_chunk, window=layer_window,
+                                schedule=schedule)
     with jax.named_scope("o_proj"):
         y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return y, new_cache
+    return y, new_rows

@@ -137,7 +137,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
         if spec.kind in (ATTN, SWA, HYBRID):
             smax = min(cfg.window, max_len) if spec.kind in (SWA, HYBRID) \
                 and cfg.window else max_len
-            c["k"] = jnp.zeros((n_periods, batch, smax, cfg.n_kv_heads,
+            c["k"] = jnp.zeros((n_periods, batch, cfg.n_kv_heads, smax,
                                 cfg.head_dim), dtype)
             c["v"] = jnp.zeros_like(c["k"])
         if spec.kind == HYBRID or spec.kind == MAMBA:
@@ -283,9 +283,10 @@ def _attention(ap, h, positions, cfg, window, opts, mode, cache, cache_pos):
             kc, vc = k, v
         with jax.named_scope("o_proj"):
             y = jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
-        return y, {"k": kc.astype(jnp.dtype(cfg.dtype)),
-                   "v": vc.astype(jnp.dtype(cfg.dtype))}
-    # decode
+        # stored head-major, (B, Hkv, S, D): see attention_block
+        return y, {"k": kc.swapaxes(1, 2).astype(jnp.dtype(cfg.dtype)),
+                   "v": vc.swapaxes(1, 2).astype(jnp.dtype(cfg.dtype))}
+    # decode: the cache is read, and the new token's K/V rows returned
     y, kv = attn_mod.attention_block(
         ap, h, positions, cfg, layer_window=window,
         kv_cache=(cache["k"], cache["v"]), cache_pos=cache_pos,
@@ -428,6 +429,11 @@ def decode_step(params, cfg: ModelConfig, cache, token=None, embed=None,
 
     token: (B,) int32 (or embed: (B,1,d) for audio).  pos: scalar int32
     absolute position of this token.  Returns (logits (B,V), new_cache).
+
+    The layer scan only reads the K/V caches and yields the new token's
+    rows, (n_periods, B, Hkv, 1, D); they are written after it, one
+    update per cache, so a caller that donates ``cache`` has it updated
+    in place.  Recurrent states come out of the scan whole.
     """
     if embed is None:
         x = jnp.take(params["embed"], token[:, None], axis=0)
@@ -438,6 +444,12 @@ def decode_step(params, cfg: ModelConfig, cache, token=None, embed=None,
     x, new_cache, _ = _stack_forward(params, x, cfg, mesh_args, opts,
                                      "decode", cache=cache, cache_pos=pos,
                                      positions=positions)
+    for ename, c in cache.items():
+        for name in ("k", "v"):
+            if name in c:
+                slot = pos % c[name].shape[3]
+                new_cache[ename][name] = jax.lax.dynamic_update_slice_in_dim(
+                    c[name], new_cache[ename][name], slot, 3)
     h = rms_norm(x, params["final_norm"])
     with jax.named_scope("unembed"):
         logits = jnp.einsum("bsd,dv->bsv", h, params["unembed"])

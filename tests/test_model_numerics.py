@@ -134,10 +134,20 @@ def test_mlstm_chunked_matches_recurrent():
                                rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "hymba-1.5b", "xlstm-125m",
-                                  "granite-moe-1b-a400m"])
-def test_prefill_then_decode_matches_forward(arch):
-    """logits(prefill(x[:t]) + decode x[t]) == logits(forward(x[:t+1]))."""
+ARCHS_DECODE = ["qwen2-1.5b", "hymba-1.5b", "xlstm-125m",
+                "granite-moe-1b-a400m"]
+
+
+@pytest.mark.parametrize("arch,steps,window", [
+    *(pytest.param(a, 1, None, id=a) for a in ARCHS_DECODE),
+    # several steps, the cache carried and donated, the last one in the
+    # cache's last slot
+    *(pytest.param(a, 4, None, id=f"{a}-4steps") for a in ARCHS_DECODE),
+    # a ring buffer the prompt fills: each step overwrites its oldest slot
+    pytest.param("hymba-1.5b", 4, 8, id="hymba-1.5b-4steps-ring")])
+def test_prefill_then_decode_matches_forward(arch, steps, window):
+    """logits(prefill(x[:S]) + decode x[S..t]) == logits(forward(x[:t+1]))
+    at every decoded position t."""
     import dataclasses
     cfg = get_config(arch).reduced()
     if cfg.moe is not None:
@@ -145,25 +155,30 @@ def test_prefill_then_decode_matches_forward(arch):
         # 17-token dispatch; disable drops for the consistency check
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    if window is not None:
+        cfg = dataclasses.replace(cfg, window=window)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     S = 16
     opts = T.ModelOptions(q_chunk=8, kv_chunk=8, ssm_chunk=4, loss_chunk=8)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S + 1), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S + steps), 0,
                                 cfg.vocab)
-    # full forward logits at position S (predicting S+1)
+    # full forward logits at positions S.. (each predicting the next)
     hidden, _ = T.forward(params, cfg, tokens, opts=opts)
     from repro.models.layers import rms_norm
-    h_last = rms_norm(hidden[:, -1], params["final_norm"])
-    want = (h_last @ params["unembed"]).astype(jnp.float32)
-    # prefill on S tokens, grow cache to S+1 slots (as the serve driver
-    # does), decode token S
+    want = (rms_norm(hidden[:, S:], params["final_norm"])
+            @ params["unembed"]).astype(jnp.float32)
+    # prefill on S tokens, grow cache to S+steps slots (as the serve
+    # driver does), then decode tokens S.. with the cache donated
     from repro.launch.serve import _grow_cache
     _, cache = T.prefill(params, cfg, tokens[:, :S], opts=opts)
-    cache = _grow_cache(cfg, cache, 1, S + 1, S)
-    got, _ = T.decode_step(params, cfg, cache, token=tokens[:, S],
-                           pos=jnp.int32(S), opts=opts)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
+    cache = _grow_cache(cfg, cache, 1, S + steps, S)
+    step = jax.jit(lambda p, c, pos, tok: T.decode_step(
+        p, cfg, c, token=tok, pos=pos, opts=opts), donate_argnums=(1,))
+    for i in range(steps):
+        got, cache = step(params, cache, jnp.int32(S + i), tokens[:, S + i])
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, i]),
+                                   rtol=2e-3, atol=2e-3,
+                                   err_msg=f"position {S + i}")
 
 
 def test_loss_label_masking():

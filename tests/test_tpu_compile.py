@@ -95,3 +95,33 @@ def test_qwen2_prefill_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     # the bf16 parameters alone are ~3.55 GB
     assert 3.0e9 < mem.argument_size_in_bytes < total < HBM_BYTES
+
+
+def test_qwen2_decode_updates_the_donated_cache_in_place(one_chip):
+    """At the serving cell's size (batch 64, 1,280 slots) the decode step
+    aliases the donated KV cache and needs less scratch than one layer's
+    K cache: no layer's cache is copied."""
+    from repro.configs import get_config
+    from repro.launch import steps as steps_mod
+    from repro.models import transformer as T
+    cfg = get_config("qwen2-1.5b")
+    batch, max_len = 64, 1280
+    opts = T.ModelOptions(q_chunk=256, kv_chunk=256, loss_chunk=256)
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+    params = specs(jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = specs(jax.eval_shape(lambda: T.init_cache(cfg, batch, max_len)))
+    step = jax.jit(steps_mod.make_decode_step(cfg, None, opts),
+                   donate_argnums=(1,))
+    mem = step.lower(params, cache, _spec(one_chip, (), jnp.int32),
+                     token=_spec(one_chip, (batch,), jnp.int32)
+                     ).compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    layer_k_bytes = cache_bytes // (2 * cfg.n_layers)
+    assert cache_bytes == 2 * 28 * 64 * 1280 * 2 * 128 * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < layer_k_bytes
