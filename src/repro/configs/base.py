@@ -18,6 +18,7 @@ MLSTM = "mlstm"          # xLSTM matrix-memory block
 SLSTM = "slstm"          # xLSTM scalar-memory block
 HYBRID = "hybrid"        # parallel attention + mamba heads (Hymba)
 MAMBA = "mamba"          # selective SSM block
+MLA = "mla"              # multi-head latent attention (DeepSeek-V2/V3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +28,12 @@ class MoEConfig:
     capacity_factor: float = 1.25
     shared_expert: bool = False  # llama4-style always-on shared expert
     moe_every: int = 1           # every Nth layer is MoE (llama4: 2)
+    d_expert: int = 0            # routed expert width (0: the model's d_ff)
+    d_shared: int = 0            # shared expert width (0: the model's d_ff)
+    # softmax | sigmoid: DeepSeek-V3's, with a bias added to the scores
+    # that selects experts only
+    scoring: str = "softmax"
+    routed_scale: float = 1.0    # routed gates scaled after normalising
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,8 +54,20 @@ class ModelConfig:
     qk_norm: bool = False       # qwen3
     qkv_bias: bool = False      # qwen2
     rope_theta: float = 10_000.0
+    norm_eps: float = 1e-6      # RMSNorm epsilon
+    # --- latent attention (MLA blocks; head_dim = nope + rope) ----------
+    kv_lora_rank: int = 0       # width of the latent K/V is cached in
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0   # one roped key per token, shared by heads
+    v_head_dim: int = 0
+    first_dense_layers: int = 0  # leading layers with a dense FFN
     # --- MoE --------------------------------------------------------------
     moe: Optional[MoEConfig] = None
+    # experts [experts_first, experts_first + experts_held) of each MoE
+    # layer live here (expert parallelism's share of one chip); 0 holds
+    # them all
+    experts_held: int = 0
+    experts_first: int = 0
     # --- SSM / recurrent ---------------------------------------------------
     ssm_state: int = 0          # mamba state size (hymba) / mlstm uses head_dim
     # --- modality frontend (stub): extra embedded inputs ------------------
@@ -68,11 +87,27 @@ class ModelConfig:
     @property
     def is_subquadratic(self) -> bool:
         """True if no block requires full quadratic attention."""
-        return all(b != ATTN for b in self.blocks)
+        return all(b not in (ATTN, MLA) for b in self.blocks)
 
     @property
     def has_kv_cache(self) -> bool:
-        return any(b in (ATTN, SWA, HYBRID) for b in self.blocks)
+        return any(b in (ATTN, SWA, HYBRID, MLA) for b in self.blocks)
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts of each MoE layer that this chip holds."""
+        return self.experts_held or self.moe.n_experts
+
+    @property
+    def d_expert(self) -> int:
+        return self.moe.d_expert or self.d_ff
+
+    def _mla_params(self) -> int:
+        d, h = self.d_model, self.n_heads
+        r, nope = self.kv_lora_rank, self.qk_nope_head_dim
+        rope, dv = self.qk_rope_head_dim, self.v_head_dim
+        return (d * h * (nope + rope) + d * (r + rope) + r
+                + r * h * (nope + dv) + h * dv * d)
 
     def n_params(self) -> int:
         """Approximate parameter count (embeddings + blocks)."""
@@ -84,6 +119,8 @@ class ModelConfig:
             if b in (ATTN, SWA):
                 total += d * (qd + 2 * kvd) + qd * d          # qkv + o
                 total += self._ffn_params(i)
+            elif b == MLA:
+                total += self._mla_params() + self._ffn_params(i)
             elif b == MLSTM:
                 # up-proj 2x, qkv over inner dim, gates, down-proj
                 inner = 2 * d
@@ -106,17 +143,19 @@ class ModelConfig:
         """Layer indices whose FFN is MoE."""
         if self.moe is None:
             return ()
-        ev = self.moe.moe_every
-        return tuple(i for i in range(self.n_layers)
-                     if i % ev == ev - 1 and self.blocks[i] in (ATTN, SWA))
+        ev, lead = self.moe.moe_every, self.first_dense_layers
+        return tuple(i for i in range(lead, self.n_layers)
+                     if (i - lead) % ev == ev - 1
+                     and self.blocks[i] in (ATTN, SWA, MLA))
 
     def _ffn_params(self, layer: int = 0) -> int:
         d, f = self.d_model, self.d_ff
         if self.moe is not None and layer in self.moe_layers():
             e = self.moe.n_experts
-            p = e * 3 * d * f + d * e  # experts (gated mlp) + router
+            # experts (gated mlp) + router
+            p = e * 3 * d * self.d_expert + d * e
             if self.moe.shared_expert:
-                p += 3 * d * f
+                p += 3 * d * (self.moe.d_shared or f)
             return p
         if f == 0:
             return 0
@@ -128,8 +167,8 @@ class ModelConfig:
             return self.n_params()
         full = self.n_params()
         e, k = self.moe.n_experts, self.moe.top_k
-        d, f = self.d_model, self.d_ff
-        inactive = len(self.moe_layers()) * (e - k) * 3 * d * f
+        inactive = (len(self.moe_layers()) * (e - k) * 3 * self.d_model
+                    * self.d_expert)
         return full - inactive
 
     def reduced(self) -> "ModelConfig":
@@ -138,7 +177,13 @@ class ModelConfig:
         if self.moe is not None:
             moe = dataclasses.replace(
                 self.moe, n_experts=min(4, self.moe.n_experts),
-                top_k=min(2, self.moe.top_k))
+                top_k=min(2, self.moe.top_k),
+                d_expert=min(self.moe.d_expert, 64),
+                d_shared=min(self.moe.d_shared, 64))
+        mla = {}
+        if self.kv_lora_rank:
+            mla = dict(kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
         return dataclasses.replace(
             self,
             n_layers=min(2, self.n_layers) if len(self.block_pattern) <= 2
@@ -148,11 +193,14 @@ class ModelConfig:
             n_kv_heads=min(2, self.n_kv_heads) if self.n_kv_heads < self.n_heads else 4,
             d_ff=128 if self.d_ff else 0,
             vocab=256,
-            head_dim=16,
+            head_dim=24 if mla else 16,
             window=min(self.window, 64) if self.window else 0,
             frontend_tokens=min(self.frontend_tokens, 8),
             moe=moe,
+            experts_held=min(self.experts_held, 4),
+            experts_first=0,
             dtype="float32",
+            **mla,
         )
 
 
@@ -180,7 +228,7 @@ SHAPES = {
 def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
     """(runs?, reason).  long_500k requires sub-quadratic sequence mixing."""
     if shape.name == "long_500k":
-        quad = [b for b in set(model.blocks) if b == ATTN]
+        quad = [b for b in set(model.blocks) if b in (ATTN, MLA)]
         if quad:
             return False, ("SKIP: pure full-attention blocks are quadratic/"
                            "O(S) KV at 512k; per DESIGN.md only sub-quadratic "
@@ -213,4 +261,5 @@ def _load_all() -> None:
     from repro.configs import (  # noqa: F401
         xlstm_125m, yi_6b, qwen2_1_5b, starcoder2_15b, qwen3_32b,
         llava_next_mistral_7b, llama4_maverick_400b_a17b,
-        granite_moe_1b_a400m, musicgen_large, hymba_1_5b)
+        granite_moe_1b_a400m, musicgen_large, hymba_1_5b,
+        moonlight_16b_a3b)

@@ -45,9 +45,11 @@ from repro.core.pipeline.stats import (_group_sum_ordered,  # noqa: F401
 from repro.core.pipeline.traceconv import convert_traces  # noqa: F401
 from repro.core.pipeline.unify import (GlobalTree,  # noqa: F401
                                        apply_order, canonical_order, unify)
+from repro.core.gcpause import collector_paused
 from repro.core.structure import HloModule
 
 
+@collector_paused()
 def aggregate(profile_paths: Sequence[str], out_dir: str, *,
               n_ranks: int = 4, n_threads: int = 4,
               structures: Optional[Dict[str, HloModule]] = None,

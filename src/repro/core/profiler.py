@@ -43,6 +43,7 @@ from repro.core import sampling
 from repro.core.cct import (CCT, CCTNode, Frame, HOST, PLACEHOLDER,
                             unwind_host_stack)
 from repro.core.channels import RingSet
+from repro.core.gcpause import collector_paused
 from repro.core.metrics import MetricRegistry, default_registry
 from repro.core.monitor import (ACTIVITY, OP, GpuActivity, GpuOperation,
                                 MonitorThread)
@@ -665,6 +666,7 @@ class Profiler:
             cls._graft_node(cct, cct.get_or_insert(real, frame), child)
 
     # ------------------------------------------------------------------ #
+    @collector_paused()
     def flush(self, timeout: float = 10.0) -> bool:
         """Quiesce the monitor (all rings + trace channels drained,
         in-flight batches routed), then graft the shadow CCTs into the
@@ -679,6 +681,7 @@ class Profiler:
         self._graft_shadow()
         return True
 
+    @collector_paused()
     def write(self) -> Dict[str, str]:
         """Writes all profiles + traces.  Returns {label: path}.  Raises
         RuntimeError if a measurement thread died."""

@@ -108,6 +108,9 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
             toks = jnp.asarray(rng.integers(0, cfg.vocab,
                                             (batch, prompt_len), np.int32))
             batch_in = {"tokens": toks}
+        # the last batch's cache goes before the prefill makes the next:
+        # the two are never held at once
+        cache = None
         # --- prefill ------------------------------------------------------
         with _maybe_window(serving, rid, PREFILL, batch * prompt_len):
             if prof is not None:
@@ -157,6 +160,8 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
                 gen.append(tok)
                 pos = jnp.int32(prompt_len + t + 1)
+        if T.LOAD in cache:
+            _record_load(cfg, cache[T.LOAD])
         outs.append(jnp.stack(gen, axis=1))
     paths = None
     if own_prof:
@@ -166,15 +171,30 @@ def serve(cfg: ModelConfig, *, n_requests: int = 8, batch: int = 4,
     return jnp.concatenate(outs, axis=0)[:n_requests], paths
 
 
+def _record_load(cfg, load) -> None:
+    """The batch's rows routed to each held expert, counted by the
+    decode program on the device, as the args of a ``serve.moe_load``
+    span (``expert_<id>``, and ``elsewhere`` for experts held on other
+    chips): read from the device only while a trace records it."""
+    with span("serve.moe_load") as live:
+        if live is not None:
+            rows = np.asarray(load)
+            live.set_metadata(elsewhere=int(rows[-1]), **{
+                f"expert_{cfg.experts_first + i}": int(r)
+                for i, r in enumerate(rows[:-1])})
+
+
 def _grow_cache(cfg, cache, batch, max_len, cur_len):
-    """Pad prefill KV caches out to max_len slots (attention layers only)."""
+    """Pad the prefill's per-position caches (``T.SLOT_AXES``: attention
+    K/V, latent rows) out to max_len slots."""
     def grow(path, leaf):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("k", "v") and leaf.ndim == 5 and \
-                leaf.shape[3] == cur_len:
-            pad = jnp.zeros(leaf.shape[:3] + (max_len - cur_len,)
-                            + leaf.shape[4:], leaf.dtype)
-            return jnp.concatenate([leaf, pad], axis=3)
+        axis = T.SLOT_AXES.get(name)
+        if axis is not None and leaf.shape[axis] == cur_len:
+            # one pad op: no zero block is made beside the grown cache
+            widths = [(0, 0)] * leaf.ndim
+            widths[axis] = (0, max_len - cur_len)
+            return jnp.pad(leaf, widths)
         return leaf
     return jax.tree_util.tree_map_with_path(grow, cache)
 

@@ -17,9 +17,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import (ATTN, HYBRID, MLSTM, SLSTM, SWA, MAMBA,
+from repro.configs.base import (ATTN, HYBRID, MLA, MLSTM, SLSTM, SWA, MAMBA,
                                 ModelConfig)
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
@@ -29,6 +30,16 @@ from repro.models.layers import dense_init, rms_norm, swiglu
 class EntrySpec(NamedTuple):
     kind: str
     use_moe: bool
+
+
+# Cache leaves that hold one row per position, and the axis of the slot
+# in the leaf stacked over layers: a decode step writes its new row
+# there, and serving grows the prefill's cache along it.
+SLOT_AXES = {"k": 3, "v": 3, "ckv": 2, "kpe": 2}
+# The cache leaf in which the decode program counts the rows routed to
+# each held expert (then to experts held elsewhere), over layers and
+# steps; zero after a prefill.
+LOAD = "moe_load"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,23 +57,49 @@ class ModelOptions:
 
 
 def layer_plan(cfg: ModelConfig) -> Tuple[Tuple[EntrySpec, ...], int]:
-    """Returns (period entries, n_periods)."""
+    """Returns (period entries, n_periods) of the layers after the
+    leading dense ones."""
     period = len(cfg.block_pattern)
     if cfg.moe is not None:
         period = math.lcm(period, cfg.moe.moe_every)
-    assert cfg.n_layers % period == 0, (cfg.name, cfg.n_layers, period)
+    lead = cfg.first_dense_layers
+    assert (cfg.n_layers - lead) % period == 0, (cfg.name, cfg.n_layers,
+                                                 period)
     moe_layers = set(cfg.moe_layers())
-    entries = tuple(
-        EntrySpec(cfg.blocks[i], i in moe_layers) for i in range(period))
-    return entries, cfg.n_layers // period
+    entries = tuple(EntrySpec(cfg.blocks[lead + i], lead + i in moe_layers)
+                    for i in range(period))
+    return entries, (cfg.n_layers - lead) // period
+
+
+def lead_plan(cfg: ModelConfig) -> Tuple[Tuple[EntrySpec, ...], int]:
+    """(entries, n) of the leading dense layers, which run before the
+    period scan, stacked as one entry; ((), 0) for a model with none."""
+    lead = cfg.first_dense_layers
+    if not lead:
+        return (), 0
+    kinds = set(cfg.blocks[:lead])
+    assert len(kinds) == 1, (cfg.name, kinds)
+    return (EntrySpec(cfg.blocks[0], False),), lead
+
+
+def _groups(cfg: ModelConfig):
+    """(params key, entry name prefix, entries, n) of each stack of
+    layers, in the order they run."""
+    out = []
+    entries, n = lead_plan(cfg)
+    if n:
+        out.append(("lead", "d", entries, n))
+    entries, n = layer_plan(cfg)
+    out.append(("layers", "e", entries, n))
+    return out
 
 
 # ---------------------------------------------------------------------------
 # Parameter init
 # ---------------------------------------------------------------------------
-def _init_ffn(key, cfg, dtype):
+def _init_ffn(key, cfg, dtype, width: int = 0):
     ks = jax.random.split(key, 3)
-    d, f = cfg.d_model, cfg.d_ff
+    d, f = cfg.d_model, width or cfg.d_ff
     return {"w1": dense_init(ks[0], (d, f), dtype),
             "w3": dense_init(ks[1], (d, f), dtype),
             "w2": dense_init(ks[2], (f, d), dtype)}
@@ -72,14 +109,17 @@ def _init_entry(key, spec: EntrySpec, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 6)
     d = cfg.d_model
     p: Dict[str, Any] = {"ln1": jnp.ones((d,), dtype)}
-    if spec.kind in (ATTN, SWA):
-        p["attn"] = attn_mod.init_attn_params(ks[0], cfg, dtype)
+    if spec.kind in (ATTN, SWA, MLA):
+        p["attn"] = (mla_mod.init_mla_params if spec.kind == MLA else
+                     attn_mod.init_attn_params)(ks[0], cfg, dtype)
         p["ln2"] = jnp.ones((d,), dtype)
         if spec.use_moe:
             p["moe"] = moe_mod.init_moe_params(
-                ks[1], d, cfg.d_ff, cfg.moe.n_experts, dtype)
+                ks[1], d, cfg.d_expert, cfg.moe.n_experts, dtype,
+                n_held=cfg.experts_held or None,
+                select_bias=cfg.moe.scoring == "sigmoid")
             if cfg.moe.shared_expert:
-                p["shared"] = _init_ffn(ks[2], cfg, dtype)
+                p["shared"] = _init_ffn(ks[2], cfg, dtype, cfg.moe.d_shared)
         elif cfg.d_ff:
             p["ffn"] = _init_ffn(ks[1], cfg, dtype)
     elif spec.kind == MLSTM:
@@ -105,20 +145,23 @@ def _init_entry(key, spec: EntrySpec, cfg: ModelConfig, dtype):
 
 def init_params(key, cfg: ModelConfig):
     dtype = jnp.dtype(cfg.dtype)
-    entries, n_periods = layer_plan(cfg)
     k_emb, k_out, k_layers = jax.random.split(key, 3)
     params = {
         "embed": dense_init(k_emb, (cfg.vocab, cfg.d_model), dtype,
                             scale=cfg.d_model ** 0.5),  # ~N(0,1) rows
         "unembed": dense_init(k_out, (cfg.d_model, cfg.vocab), dtype),
         "final_norm": jnp.ones((cfg.d_model,), dtype),
-        "layers": {},
     }
-    lkeys = jax.random.split(k_layers, len(entries))
-    for i, spec in enumerate(entries):
-        per_period = jax.random.split(lkeys[i], n_periods)
-        params["layers"][f"e{i}"] = jax.vmap(
-            lambda k: _init_entry(k, spec, cfg, dtype))(per_period)
+    groups = _groups(cfg)
+    gkeys = [k_layers] if len(groups) == 1 else \
+        jax.random.split(k_layers, len(groups))
+    for gkey, (group, prefix, entries, n) in zip(gkeys, groups):
+        params[group] = {}
+        lkeys = jax.random.split(gkey, len(entries))
+        for i, spec in enumerate(entries):
+            per_period = jax.random.split(lkeys[i], n)
+            params[group][f"{prefix}{i}"] = jax.vmap(
+                lambda k: _init_entry(k, spec, cfg, dtype))(per_period)
     return params
 
 
@@ -126,52 +169,90 @@ def init_params(key, cfg: ModelConfig):
 # Caches (serving state per entry)
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, max_len: int):
-    """Zero cache pytree, stacked over periods: {'e0': {...}, ...}."""
+    """Zero cache pytree, stacked over periods: {'e0': {...}, ...}; the
+    leading dense layers' entries are 'd0', ...; a model whose layers
+    are told which experts they hold has the load counter too."""
+    cache = {}
+    for _, prefix, entries, n in _groups(cfg):
+        for i, spec in enumerate(entries):
+            cache[f"{prefix}{i}"] = _entry_cache(cfg, spec, n, batch,
+                                                 max_len)
+    if cfg.experts_held:
+        cache[LOAD] = _zero_load(cfg)
+    return cache
+
+
+def _zero_load(cfg: ModelConfig):
+    return jnp.zeros((cfg.n_experts_held + 1,), jnp.int32)
+
+
+def _entry_cache(cfg: ModelConfig, spec: EntrySpec, n_periods: int,
+                 batch: int, max_len: int):
     dtype = jnp.dtype(cfg.dtype)
-    entries, n_periods = layer_plan(cfg)
     d = cfg.d_model
     inner = cfg.n_heads * cfg.head_dim
-    cache = {}
-    for i, spec in enumerate(entries):
-        c: Dict[str, Any] = {}
-        if spec.kind in (ATTN, SWA, HYBRID):
-            smax = min(cfg.window, max_len) if spec.kind in (SWA, HYBRID) \
-                and cfg.window else max_len
-            c["k"] = jnp.zeros((n_periods, batch, cfg.n_kv_heads, smax,
-                                cfg.head_dim), dtype)
-            c["v"] = jnp.zeros_like(c["k"])
-        if spec.kind == HYBRID or spec.kind == MAMBA:
-            c["ssm"] = jnp.zeros((n_periods, batch, cfg.n_heads,
-                                  cfg.head_dim, cfg.ssm_state), jnp.float32)
-            c["conv"] = jnp.zeros((n_periods, batch, ssm_mod.CONV_W - 1,
-                                   inner), dtype)
-        if spec.kind == MLSTM:
-            dv = 2 * d // cfg.n_heads
-            c["H"] = jnp.zeros((n_periods, batch, cfg.n_heads,
-                                cfg.head_dim, dv + 1), jnp.float32)
-            c["m"] = jnp.full((n_periods, batch, cfg.n_heads), -1e30,
-                              jnp.float32)
-        if spec.kind == SLSTM:
-            for name in ("c", "n", "h"):
-                c[name] = jnp.zeros((n_periods, batch, d), jnp.float32)
-            c["m"] = jnp.full((n_periods, batch, d), -1e30, jnp.float32)
-        cache[f"e{i}"] = c
-    return cache
+    c: Dict[str, Any] = {}
+    if spec.kind == MLA:
+        c["ckv"] = jnp.zeros((n_periods, batch, max_len, cfg.kv_lora_rank),
+                             dtype)
+        c["kpe"] = jnp.zeros((n_periods, batch, max_len,
+                              cfg.qk_rope_head_dim), dtype)
+    if spec.kind in (ATTN, SWA, HYBRID):
+        smax = min(cfg.window, max_len) if spec.kind in (SWA, HYBRID) \
+            and cfg.window else max_len
+        c["k"] = jnp.zeros((n_periods, batch, cfg.n_kv_heads, smax,
+                            cfg.head_dim), dtype)
+        c["v"] = jnp.zeros_like(c["k"])
+    if spec.kind == HYBRID or spec.kind == MAMBA:
+        c["ssm"] = jnp.zeros((n_periods, batch, cfg.n_heads,
+                              cfg.head_dim, cfg.ssm_state), jnp.float32)
+        c["conv"] = jnp.zeros((n_periods, batch, ssm_mod.CONV_W - 1,
+                               inner), dtype)
+    if spec.kind == MLSTM:
+        dv = 2 * d // cfg.n_heads
+        c["H"] = jnp.zeros((n_periods, batch, cfg.n_heads,
+                            cfg.head_dim, dv + 1), jnp.float32)
+        c["m"] = jnp.full((n_periods, batch, cfg.n_heads), -1e30,
+                          jnp.float32)
+    if spec.kind == SLSTM:
+        for name in ("c", "n", "h"):
+            c[name] = jnp.zeros((n_periods, batch, d), jnp.float32)
+        c["m"] = jnp.full((n_periods, batch, d), -1e30, jnp.float32)
+    return c
 
 
 # ---------------------------------------------------------------------------
 # Block application
 # ---------------------------------------------------------------------------
-def _apply_ffn(p, x, cfg, mesh_args, opts):
-    """Dense or MoE FFN sub-block.  Returns (y, aux)."""
+def _apply_ffn(p, x, cfg, mesh_args, opts, mode="train", new_cache=None):
+    """Dense or MoE FFN sub-block.  Returns (y, aux).
+
+    A MoE layer told which experts it holds (``cfg.experts_held``)
+    serves dropless, and in a decode step puts the rows it routed to
+    each of them, then to experts held elsewhere, in ``new_cache[LOAD]``.
+    """
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
-        y, aux = moe_mod.moe_ffn(
-            p["moe"], x, n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
-            capacity_factor=cfg.moe.capacity_factor, mesh_args=mesh_args)
+        m = cfg.moe
+        if cfg.experts_held and mode != "train":
+            B, S, d = x.shape
+            y, load = moe_mod.held_moe(
+                p["moe"], x.reshape(B * S, d), top_k=m.top_k,
+                e_first=cfg.experts_first, scoring=m.scoring,
+                routed_scale=m.routed_scale)
+            y = y.reshape(B, S, d)
+            if mode == "decode":
+                new_cache[LOAD] = load
+        else:
+            y, aux = moe_mod.moe_ffn(
+                p["moe"], x, n_experts=m.n_experts, top_k=m.top_k,
+                capacity_factor=m.capacity_factor, mesh_args=mesh_args,
+                e_first=cfg.experts_first, scoring=m.scoring,
+                routed_scale=m.routed_scale)
         if "shared" in p:
-            y = y + swiglu(x, p["shared"]["w1"], p["shared"]["w3"],
-                           p["shared"]["w2"])
+            with jax.named_scope("moe_shared"):
+                y = y + swiglu(x, p["shared"]["w1"], p["shared"]["w3"],
+                               p["shared"]["w2"])
     elif "ffn" in p:
         y = swiglu(x, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
     else:
@@ -184,17 +265,21 @@ def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
     """One block.  Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {} if cache is not None or mode != "train" else None
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
 
-    if spec.kind in (ATTN, SWA):
-        window = cfg.window if spec.kind == SWA else 0
-        y, kv = _attention(p["attn"], h, positions, cfg, window, opts,
-                           mode, cache, cache_pos)
+    if spec.kind in (ATTN, SWA, MLA):
+        if spec.kind == MLA:
+            y, kv = _mla(p["attn"], h, positions, cfg, opts, mode, cache,
+                         cache_pos)
+        else:
+            window = cfg.window if spec.kind == SWA else 0
+            y, kv = _attention(p["attn"], h, positions, cfg, window, opts,
+                               mode, cache, cache_pos)
         if kv is not None:
             new_cache.update(kv)
         x = x + y
-        h2 = rms_norm(x, p["ln2"])
-        y2, aux = _apply_ffn(p, h2, cfg, mesh_args, opts)
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        y2, aux = _apply_ffn(p, h2, cfg, mesh_args, opts, mode, new_cache)
         x = x + y2
     elif spec.kind == MLSTM:
         state = (cache["H"], cache["m"]) if cache is not None else None
@@ -235,8 +320,8 @@ def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
             new_cache.update(kv or {})
             new_cache.update({"ssm": st, "conv": cv})
         x = x + y
-        h2 = rms_norm(x, p["ln2"])
-        y2, aux = _apply_ffn(p, h2, cfg, mesh_args, opts)
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        y2, aux = _apply_ffn(p, h2, cfg, mesh_args, opts, mode, new_cache)
         x = x + y2
     elif spec.kind == MAMBA:
         ssm_state = conv_state = None
@@ -255,6 +340,23 @@ def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
             x, jax.sharding.NamedSharding(
                 mesh_args.mesh, P(tuple(mesh_args.dp_axes), None, None)))
     return x, new_cache, aux
+
+
+def _mla(ap, h, positions, cfg, opts, mode, cache, cache_pos):
+    """Latent attention across the three modes.  Returns (y, cache rows):
+    a prefill's (B, S, ...), a decode step's new row (B, 1, ...), of the
+    latent (``ckv``) and of the roped key (``kpe``)."""
+    if mode == "decode":
+        y, rows = mla_mod.mla_decode(ap, h, cfg, positions, cache["ckv"],
+                                     cache["kpe"], cache_pos)
+    else:
+        y, rows = mla_mod.mla_attention(ap, h, cfg, positions,
+                                        q_chunk=opts.q_chunk,
+                                        kv_chunk=opts.kv_chunk,
+                                        schedule=opts.attn_schedule)
+    if mode == "train":
+        return y, None
+    return y, dict(zip(("ckv", "kpe"), rows))
 
 
 def _attention(ap, h, positions, cfg, window, opts, mode, cache, cache_pos):
@@ -319,18 +421,41 @@ def embed_inputs(params, cfg: ModelConfig, tokens, embeds):
 
 def _stack_forward(params, x, cfg, mesh_args, opts, mode,
                    cache=None, cache_pos=None, positions=None):
-    """Runs the scan over periods.  Returns (x, new_cache, aux_sum)."""
-    entries, n_periods = layer_plan(cfg)
+    """Runs the leading dense layers, if any, then the scan over periods.
+    Returns (x, new_cache, aux_sum); in a decode step ``new_cache[LOAD]``
+    holds the rows the step routed to each held expert, over layers."""
+    aux_sum = None
+    new_cache = {} if mode != "train" else None
+    for group, prefix, entries, _ in _groups(cfg):
+        names = [f"{prefix}{i}" for i in range(len(entries))]
+        gcache = None if cache is None else {n: cache[n] for n in names}
+        x, gnew, aux = _scan_layers(
+            params[group], entries, names, x, cfg, mesh_args, opts, mode,
+            gcache, cache_pos, positions,
+            scope="block" if prefix == "e" else "dense")
+        aux_sum = aux if aux_sum is None else aux_sum + aux
+        if gnew is not None:
+            new_cache.update(gnew)
+    if mode == "decode":
+        loads = [c.pop(LOAD).sum(0) for c in new_cache.values()
+                 if LOAD in c]
+        if loads:
+            new_cache[LOAD] = sum(loads)
+    return x, new_cache, aux_sum
+
+
+def _scan_layers(layer_params, entries, names, x, cfg, mesh_args, opts,
+                 mode, cache, cache_pos, positions, scope):
+    """One stack of layers as a scan.  Returns (x, new_cache, aux_sum)."""
 
     def body(carry, xs):
         x, aux_sum = carry
         layer_p = xs["params"]
         layer_c = xs.get("cache")
         new_c = {}
-        for i, spec in enumerate(entries):
-            ename = f"e{i}"
+        for i, (spec, ename) in enumerate(zip(entries, names)):
             c = layer_c[ename] if layer_c is not None else None
-            with jax.named_scope(f"block_{spec.kind}{i}"):
+            with jax.named_scope(f"{scope}_{spec.kind}{i}"):
                 x, nc, aux = _apply_entry(
                     layer_p[ename], spec, x, positions, cfg, mesh_args, opts,
                     mode, cache=c, cache_pos=cache_pos)
@@ -342,7 +467,7 @@ def _stack_forward(params, x, cfg, mesh_args, opts, mode,
         body = jax.checkpoint(body, policy=_remat_policy(opts),
                               prevent_cse=False)
 
-    xs = {"params": params["layers"]}
+    xs = {"params": layer_params}
     if cache is not None:
         xs["cache"] = cache
     (x, aux_sum), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
@@ -358,7 +483,7 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
     positions = jnp.arange(S)
     x, _, aux = _stack_forward(params, x, cfg, mesh_args, opts, "train",
                                positions=positions)
-    return rms_norm(x, params["final_norm"]), aux
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def lm_loss(params, cfg: ModelConfig, hidden, labels, *,
@@ -416,7 +541,9 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None, *,
     positions = jnp.arange(S)
     x, cache, _ = _stack_forward(params, x, cfg, mesh_args, opts, "prefill",
                                  positions=positions)
-    h_last = rms_norm(x[:, -1:], params["final_norm"])
+    if cfg.experts_held:
+        cache[LOAD] = _zero_load(cfg)
+    h_last = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     with jax.named_scope("unembed"):
         logits = jnp.einsum("bsd,dv->bsv", h_last, params["unembed"])
     return logits[:, 0].astype(jnp.float32), cache
@@ -430,10 +557,12 @@ def decode_step(params, cfg: ModelConfig, cache, token=None, embed=None,
     token: (B,) int32 (or embed: (B,1,d) for audio).  pos: scalar int32
     absolute position of this token.  Returns (logits (B,V), new_cache).
 
-    The layer scan only reads the K/V caches and yields the new token's
-    rows, (n_periods, B, Hkv, 1, D); they are written after it, one
-    update per cache, so a caller that donates ``cache`` has it updated
-    in place.  Recurrent states come out of the scan whole.
+    The layer scan only reads the K/V (and latent) caches and yields the
+    new token's rows, (n_periods, B, Hkv, 1, D) (latent: (n_periods, B,
+    1, r) and (n_periods, B, 1, rope)); they are written after it, one update per cache at
+    its ``SLOT_AXES`` axis, so a caller that donates ``cache`` has it
+    updated in place.  Recurrent states come out of the scan whole; the
+    held experts' load counter, if any, grows by this step's rows.
     """
     if embed is None:
         x = jnp.take(params["embed"], token[:, None], axis=0)
@@ -445,12 +574,15 @@ def decode_step(params, cfg: ModelConfig, cache, token=None, embed=None,
                                      "decode", cache=cache, cache_pos=pos,
                                      positions=positions)
     for ename, c in cache.items():
-        for name in ("k", "v"):
+        if ename == LOAD:
+            new_cache[LOAD] = c + new_cache[LOAD]
+            continue
+        for name, axis in SLOT_AXES.items():
             if name in c:
-                slot = pos % c[name].shape[3]
+                slot = pos % c[name].shape[axis]
                 new_cache[ename][name] = jax.lax.dynamic_update_slice_in_dim(
-                    c[name], new_cache[ename][name], slot, 3)
-    h = rms_norm(x, params["final_norm"])
+                    c[name], new_cache[ename][name], slot, axis)
+    h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("unembed"):
         logits = jnp.einsum("bsd,dv->bsv", h, params["unembed"])
     return logits[:, 0].astype(jnp.float32), new_cache

@@ -32,11 +32,11 @@ def make_batch(cfg, B=2, S=32, with_labels=True):
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     expected = {"xlstm-125m", "yi-6b", "qwen2-1.5b", "starcoder2-15b",
                 "qwen3-32b", "llava-next-mistral-7b",
                 "llama4-maverick-400b-a17b", "granite-moe-1b-a400m",
-                "musicgen-large", "hymba-1.5b"}
+                "musicgen-large", "hymba-1.5b", "moonlight-16b-a3b"}
     assert set(ARCHS) == expected
 
 
@@ -96,6 +96,7 @@ def test_config_exact_dims(arch):
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155),
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
@@ -111,6 +112,12 @@ def test_config_exact_dims(arch):
         assert cfg.qk_norm
     if arch == "qwen2-1.5b":
         assert cfg.qkv_bias
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert cfg.first_dense_layers == 1 and cfg.moe.d_expert == 1408
+        assert cfg.moe.n_experts == 64 and cfg.moe.top_k == 6
+        assert cfg.moe.d_shared == 2816 and cfg.moe.routed_scale == 2.446
 
 
 def test_long_500k_applicability():
@@ -121,7 +128,8 @@ def test_long_500k_applicability():
 
 
 @pytest.mark.parametrize("arch", ["llama4-maverick-400b-a17b",
-                                  "granite-moe-1b-a400m"])
+                                  "granite-moe-1b-a400m",
+                                  "moonlight-16b-a3b"])
 def test_moe_param_accounting(arch):
     cfg = get_config(arch)
     assert cfg.n_active_params() < cfg.n_params()

@@ -174,3 +174,61 @@ def test_flush_raises_when_records_do_not_drain(tmp_path):
         pass
     with pytest.raises(TimeoutError):
         prof.flush(timeout=0.05)
+
+
+def test_database_build_runs_with_the_collector_paused(tmp_path, compiled,
+                                                       monkeypatch):
+    """flush(), write() and aggregate() run with the cyclic collector
+    paused, so that no full pass over the heap falls inside a build, and
+    leave it enabled after, also when they raise."""
+    import gc
+    import importlib
+    from repro.core import profiler as profiler_mod
+    from repro.core.pipeline import driver
+    aggregate = importlib.import_module("repro.core.aggregate").aggregate
+    seen = {}
+
+    def spy(name, fn):
+        def inner(*a, **k):
+            seen[name] = gc.isenabled()
+            return fn(*a, **k)
+        return inner
+    monkeypatch.setattr(Profiler, "_graft_shadow",
+                        spy("flush", Profiler._graft_shadow))
+    monkeypatch.setattr(profiler_mod, "write_profile",
+                        spy("write", profiler_mod.write_profile))
+    monkeypatch.setattr(driver, "run", spy("aggregate", driver.run))
+    comp, x = compiled
+    prof = Profiler(str(tmp_path / "m"), tracing=True, rng_seed=0)
+    mid = prof.register_module("f", comp.as_text())
+    prof.start()
+    with prof.dispatch("kernel", "f", stream=0, module_id=mid):
+        jax.block_until_ready(comp(x))
+    assert gc.isenabled()
+    prof.flush()
+    paths = prof.write()
+    profs = [p for k, p in sorted(paths.items()) if "trace" not in k]
+    aggregate(profs, str(tmp_path / "db"), n_ranks=1, n_threads=1)
+    prof.stop()
+    assert seen == {"flush": False, "write": False, "aggregate": False}
+    assert gc.isenabled()
+    with pytest.raises(FileNotFoundError):
+        aggregate([str(tmp_path / "missing.rpro")], str(tmp_path / "db2"))
+    assert gc.isenabled()
+
+
+def test_collector_pause_leaves_the_collector_as_found():
+    import gc
+    from repro.core.gcpause import collector_paused
+    with collector_paused():
+        with collector_paused():
+            assert not gc.isenabled()
+        assert not gc.isenabled()
+    assert gc.isenabled()
+    gc.disable()
+    try:
+        with collector_paused():
+            pass
+        assert not gc.isenabled()
+    finally:
+        gc.enable()

@@ -125,3 +125,38 @@ def test_qwen2_decode_updates_the_donated_cache_in_place(one_chip):
     assert cache_bytes == 2 * 28 * 64 * 1280 * 2 * 128 * 2
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < layer_k_bytes
+
+
+def test_moonlight_decode_updates_the_latent_cache_in_place(one_chip):
+    """At its serving cell's size (8 of 64 experts held, batch 128, 1,280
+    slots) the decode step aliases the donated latent cache of all 27
+    layers and the load counter, and the step fits one chip with the
+    weights and the cache: no layer's cache is copied."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.launch import steps as steps_mod
+    from repro.models import transformer as T
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"),
+                              experts_held=8)
+    batch, max_len = 128, 1280
+    opts = T.ModelOptions(q_chunk=256, kv_chunk=256, loss_chunk=256)
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+    params = specs(jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = specs(jax.eval_shape(lambda: T.init_cache(cfg, batch, max_len)))
+    step = jax.jit(steps_mod.make_decode_step(cfg, None, opts),
+                   donate_argnums=(1,))
+    mem = step.lower(params, cache, _spec(one_chip, (), jnp.int32),
+                     token=_spec(one_chip, (batch,), jnp.int32)
+                     ).compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert cache_bytes == 27 * 128 * 1280 * 576 * 2 + 9 * 4
+    # the chip pads the 9-entry counter to one tile (512 B)
+    assert cache_bytes <= mem.alias_size_in_bytes < cache_bytes + 1024
+    layer_bytes = cache_bytes // 27
+    assert mem.temp_size_in_bytes < 3 * layer_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
