@@ -10,14 +10,15 @@ _KSTRUCT_CACHE = None
 
 
 def kernel_structures():
-    """Recover (and cache) the interior structures of all three Pallas
+    """Recover (and cache) the interior structures of all four Pallas
     kernels.  Tracing needs jax; callers on jax-less hosts should catch
     ImportError."""
     global _KSTRUCT_CACHE
     if _KSTRUCT_CACHE is None:
         from repro.kernels import (decode_attention, flash_attention,
-                                   ssm_scan)
+                                   mla_decode, ssm_scan)
         _KSTRUCT_CACHE = (flash_attention.kernel_structure(),
                           decode_attention.kernel_structure(),
-                          ssm_scan.kernel_structure())
+                          ssm_scan.kernel_structure(),
+                          mla_decode.kernel_structure())
     return _KSTRUCT_CACHE

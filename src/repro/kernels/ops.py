@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _fd
 from repro.kernels import flash_attention as _fa
+from repro.kernels import mla_decode as _md
 from repro.kernels import ssm_scan as _ss
 
 
@@ -81,6 +82,19 @@ def flash_decode(q, k_cache, v_cache, length, block_kv: int = 512):
     return _fd.flash_decode_fwd(q, k_cache, v_cache, length,
                                 block_kv=block_kv,
                                 interpret=_use_interpret())
+
+
+# ===========================================================================
+# latent-attention decode
+# ===========================================================================
+def mla_decode(q_lat, q_pe, c_new, pe_new, c_cache, pe_cache, pos, layer,
+               *, scale: float):
+    """Latent-attention decode over one layer of the stacked latent
+    caches, up to ``pos``: (B,H,r) queries -> (B,H,r) averaged latent.
+    Inference-only (no VJP needed)."""
+    return _md.mla_decode_fwd(q_lat, q_pe, c_new, pe_new, c_cache,
+                              pe_cache, pos, layer, scale=scale,
+                              interpret=_use_interpret())
 
 
 # ===========================================================================

@@ -186,14 +186,15 @@ def _record_load(cfg, load) -> None:
 
 def _grow_cache(cfg, cache, batch, max_len, cur_len):
     """Pad the prefill's per-position caches (``T.SLOT_AXES``: attention
-    K/V, latent rows) out to max_len slots."""
+    K/V, latent rows) out to the slots that hold max_len
+    (``T.cache_slots``)."""
     def grow(path, leaf):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         axis = T.SLOT_AXES.get(name)
         if axis is not None and leaf.shape[axis] == cur_len:
             # one pad op: no zero block is made beside the grown cache
             widths = [(0, 0)] * leaf.ndim
-            widths[axis] = (0, max_len - cur_len)
+            widths[axis] = (0, T.cache_slots(name, max_len) - cur_len)
             return jnp.pad(leaf, widths)
         return leaf
     return jax.tree_util.tree_map_with_path(grow, cache)

@@ -15,7 +15,10 @@ touches every tile).  Prefill expands K and V from the latent; decode
 reads only the rows, with ``Wkb`` absorbed into the query
 (``q_nope Wkb^T`` is a query in the latent space, scored against ``c``)
 and ``Wvb`` into the output (the probabilities average ``c``, which
-``Wvb`` then lifts to each head's value).
+``Wvb`` then lifts to each head's value).  On a TPU that average is one
+Pallas kernel (``kernels/mla_decode.py``) that reads each cache row up
+to the position once; elsewhere two einsums over every slot, masked,
+compute it and are the kernel's reference.
 """
 from __future__ import annotations
 
@@ -92,13 +95,20 @@ def mla_attention(params, h, cfg, positions, *, q_chunk: int,
     return y, (c, k_pe)
 
 
-def mla_decode(params, h, cfg, positions, c_cache, pe_cache, pos):
-    """One decode step.  h: (B, 1, d) normed; c_cache (B, Smax, r) and
-    pe_cache (B, Smax, rope) hold the rows of positions before ``pos``;
-    the slots from ``pos`` on are masked, and the new token's own row is
-    one more column of the same softmax.  The caches are only read.
-    Returns (y (B,1,d), the new rows (c (B,1,r), k_pe (B,1,rope)) for the
-    caller to write at slot ``pos``)."""
+def _use_kernel() -> bool:
+    """The Pallas kernel runs on a TPU; elsewhere the einsums below are
+    the path and its reference."""
+    return jax.default_backend() == "tpu"
+
+
+def mla_decode(params, h, cfg, positions, c_cache, pe_cache, pos, layer):
+    """One decode step.  h: (B, 1, d) normed; c_cache (L, B, Smax, r) and
+    pe_cache (L, B, Smax, rope) are every layer's caches, of which
+    ``layer``'s is read: the rows of positions before ``pos``; the slots
+    from ``pos`` on are masked, and the new token's own row is one more
+    column of the same softmax.  The caches are only read.  Returns
+    (y (B,1,d), the new rows (c (B,1,r), k_pe (B,1,rope)) for the caller
+    to write at slot ``pos``)."""
     q_nope, q_pe, (c, k_pe) = project(params, h, cfg, positions)
     c_new = c[:, 0].astype(c_cache.dtype)                    # (B, r)
     pe_new = k_pe[:, 0].astype(pe_cache.dtype)               # (B, rope)
@@ -107,6 +117,24 @@ def mla_decode(params, h, cfg, positions, c_cache, pe_cache, pos):
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0],
                            params["wkb"]).astype(c_cache.dtype)
         q_pe = q_pe[:, 0].astype(pe_cache.dtype)
+    if _use_kernel():
+        from repro.kernels import ops
+        with jax.named_scope("mla_scores"):
+            o = ops.mla_decode(q_lat, q_pe, c_new, pe_new, c_cache,
+                               pe_cache, pos, layer, scale=scale)
+    else:
+        o = _attend(q_lat, q_pe, c_new, pe_new, c_cache[layer],
+                    pe_cache[layer], pos, scale)
+    with jax.named_scope("mla_out"):
+        ov = jnp.einsum("bhr,rhv->bhv", o.astype(h.dtype), params["wvb"])
+        y = jnp.einsum("bhv,hvd->bd", ov, params["wo"])
+    return y[:, None], (c_new[:, None], pe_new[:, None])
+
+
+def _attend(q_lat, q_pe, c_new, pe_new, c_cache, pe_cache, pos, scale):
+    """The kernel's reference: masked scores over all of one layer's
+    slots, then the probabilities against the same cache.  Returns
+    (B, H, r) in f32."""
     with jax.named_scope("mla_scores"):
         valid = jnp.arange(c_cache.shape[1]) < pos
         f32 = jnp.float32
@@ -126,7 +154,4 @@ def mla_decode(params, h, cfg, positions, c_cache, pe_cache, pos):
     with jax.named_scope("mla_out"):
         o = jnp.einsum("bhs,bsr->bhr", (e / denom[..., None]).astype(
             c_cache.dtype), c_cache, preferred_element_type=f32)
-        o = o + (e_new / denom)[..., None] * c_new[:, None].astype(f32)
-        ov = jnp.einsum("bhr,rhv->bhv", o.astype(h.dtype), params["wvb"])
-        y = jnp.einsum("bhv,hvd->bd", ov, params["wo"])
-    return y[:, None], (c_new[:, None], pe_new[:, None])
+        return o + (e_new / denom)[..., None] * c_new[:, None].astype(f32)

@@ -36,6 +36,23 @@ class EntrySpec(NamedTuple):
 # in the leaf stacked over layers: a decode step writes its new row
 # there, and serving grows the prefill's cache along it.
 SLOT_AXES = {"k": 3, "v": 3, "ckv": 2, "kpe": 2}
+# Cache leaves that a decode step reads whole, stacked over layers, at
+# the layer's index (``mla.mla_decode``): a layer's slice of them handed
+# to a kernel would be copied first.
+WHOLE = ("ckv", "kpe")
+# Their slots come in whole tiles of this many: the chip then keeps the
+# 64-wide roped keys slot-minor, the layout the latent-attention kernel
+# reads as it is; at a length off the tile it puts the batch axis minor,
+# and every step would relayout the whole stack for the kernel.
+SLOT_TILE = 128
+
+
+def cache_slots(name: str, max_len: int) -> int:
+    """Slots of cache leaf ``name`` that hold sequences of ``max_len``."""
+    return -(-max_len // SLOT_TILE) * SLOT_TILE if name in WHOLE \
+        else max_len
+
+
 # The cache leaf in which the decode program counts the rows routed to
 # each held expert (then to experts held elsewhere), over layers and
 # steps; zero after a prefill.
@@ -193,9 +210,10 @@ def _entry_cache(cfg: ModelConfig, spec: EntrySpec, n_periods: int,
     inner = cfg.n_heads * cfg.head_dim
     c: Dict[str, Any] = {}
     if spec.kind == MLA:
-        c["ckv"] = jnp.zeros((n_periods, batch, max_len, cfg.kv_lora_rank),
+        slots = cache_slots("ckv", max_len)
+        c["ckv"] = jnp.zeros((n_periods, batch, slots, cfg.kv_lora_rank),
                              dtype)
-        c["kpe"] = jnp.zeros((n_periods, batch, max_len,
+        c["kpe"] = jnp.zeros((n_periods, batch, slots,
                               cfg.qk_rope_head_dim), dtype)
     if spec.kind in (ATTN, SWA, HYBRID):
         smax = min(cfg.window, max_len) if spec.kind in (SWA, HYBRID) \
@@ -261,8 +279,9 @@ def _apply_ffn(p, x, cfg, mesh_args, opts, mode="train", new_cache=None):
 
 
 def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
-                 mode: str, cache=None, cache_pos=None):
-    """One block.  Returns (x, new_cache, aux)."""
+                 mode: str, cache=None, cache_pos=None, layer=None):
+    """One block; ``layer`` is its index in the stack whose ``WHOLE``
+    cache leaves ``cache`` holds.  Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {} if cache is not None or mode != "train" else None
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -270,7 +289,7 @@ def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
     if spec.kind in (ATTN, SWA, MLA):
         if spec.kind == MLA:
             y, kv = _mla(p["attn"], h, positions, cfg, opts, mode, cache,
-                         cache_pos)
+                         cache_pos, layer)
         else:
             window = cfg.window if spec.kind == SWA else 0
             y, kv = _attention(p["attn"], h, positions, cfg, window, opts,
@@ -342,13 +361,13 @@ def _apply_entry(p, spec: EntrySpec, x, positions, cfg, mesh_args, opts,
     return x, new_cache, aux
 
 
-def _mla(ap, h, positions, cfg, opts, mode, cache, cache_pos):
+def _mla(ap, h, positions, cfg, opts, mode, cache, cache_pos, layer):
     """Latent attention across the three modes.  Returns (y, cache rows):
     a prefill's (B, S, ...), a decode step's new row (B, 1, ...), of the
     latent (``ckv``) and of the roped key (``kpe``)."""
     if mode == "decode":
         y, rows = mla_mod.mla_decode(ap, h, cfg, positions, cache["ckv"],
-                                     cache["kpe"], cache_pos)
+                                     cache["kpe"], cache_pos, layer)
     else:
         y, rows = mla_mod.mla_attention(ap, h, cfg, positions,
                                         q_chunk=opts.q_chunk,
@@ -448,6 +467,15 @@ def _scan_layers(layer_params, entries, names, x, cfg, mesh_args, opts,
                  mode, cache, cache_pos, positions, scope):
     """One stack of layers as a scan.  Returns (x, new_cache, aux_sum)."""
 
+    # a decode step's WHOLE leaves stay out of the scanned slices: every
+    # layer is handed them stacked, with its index
+    whole = {}
+    if mode == "decode" and cache is not None:
+        whole = {e: {n: c[n] for n in WHOLE if n in c}
+                 for e, c in cache.items()}
+        cache = {e: {n: v for n, v in c.items() if n not in WHOLE}
+                 for e, c in cache.items()}
+
     def body(carry, xs):
         x, aux_sum = carry
         layer_p = xs["params"]
@@ -455,10 +483,13 @@ def _scan_layers(layer_params, entries, names, x, cfg, mesh_args, opts,
         new_c = {}
         for i, (spec, ename) in enumerate(zip(entries, names)):
             c = layer_c[ename] if layer_c is not None else None
+            if whole.get(ename):
+                c = {**c, **whole[ename]}
             with jax.named_scope(f"{scope}_{spec.kind}{i}"):
                 x, nc, aux = _apply_entry(
                     layer_p[ename], spec, x, positions, cfg, mesh_args, opts,
-                    mode, cache=c, cache_pos=cache_pos)
+                    mode, cache=c, cache_pos=cache_pos,
+                    layer=xs.get("layer"))
             new_c[ename] = nc
             aux_sum = aux_sum + aux
         return (x, aux_sum), (new_c if mode != "train" else None)
@@ -470,6 +501,8 @@ def _scan_layers(layer_params, entries, names, x, cfg, mesh_args, opts,
     xs = {"params": layer_params}
     if cache is not None:
         xs["cache"] = cache
+    if jax.tree.leaves(whole):
+        xs["layer"] = jnp.arange(jax.tree.leaves(whole)[0].shape[0])
     (x, aux_sum), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                            xs)
     return x, new_cache, aux_sum
@@ -557,11 +590,12 @@ def decode_step(params, cfg: ModelConfig, cache, token=None, embed=None,
     token: (B,) int32 (or embed: (B,1,d) for audio).  pos: scalar int32
     absolute position of this token.  Returns (logits (B,V), new_cache).
 
-    The layer scan only reads the K/V (and latent) caches and yields the
-    new token's rows, (n_periods, B, Hkv, 1, D) (latent: (n_periods, B,
-    1, r) and (n_periods, B, 1, rope)); they are written after it, one update per cache at
-    its ``SLOT_AXES`` axis, so a caller that donates ``cache`` has it
-    updated in place.  Recurrent states come out of the scan whole; the
+    The layer scan only reads the K/V (and latent) caches, the latent
+    ones whole at each layer's index, and yields the new token's rows,
+    (n_periods, B, Hkv, 1, D) (latent: (n_periods, B, 1, r) and
+    (n_periods, B, 1, rope)); they are written after it, one update per
+    cache at its ``SLOT_AXES`` axis, so a caller that donates ``cache``
+    has it updated in place.  Recurrent states come out of the scan whole; the
     held experts' load counter, if any, grows by this step's rows.
     """
     if embed is None:

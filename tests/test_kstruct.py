@@ -191,7 +191,7 @@ def recovered():
 
 def test_recovers_all_three_kernels(recovered):
     assert set(recovered) == {"flash_attention", "decode_attention",
-                              "ssm_scan"}
+                              "ssm_scan", "mla_decode"}
     for ks in recovered.values():
         assert len(ks.leaves) >= 10
         assert ks.active_s > 0 and ks.total_flops > 0
@@ -229,6 +229,24 @@ def test_decode_and_ssm_interiors(recovered):
     dots = [lf for lf in ssm.leaves
             if lf.frames[-1].name == "dot_general"]
     assert len(dots) >= 3
+
+
+def test_mla_decode_interior(recovered):
+    """The latent-attention decode kernel: the slot blocks are its
+    sequential loop; a block's body holds its MXU products (the scores
+    against the latent and roped keys, one line; the probabilities
+    against the latent, another), and the new token's column starts the
+    softmax in ``_init``."""
+    ks = recovered["mla_decode"]
+    assert ks.file == "mla_decode.py"
+    assert all(lf.frames[0].name == "grid:slot_blocks" for lf in ks.leaves)
+    dots = [lf for lf in ks.leaves if lf.frames[-1].name == "dot_general"
+            and any(f.name == "_block" for f in lf.frames)]
+    assert len(dots) >= 2
+    assert all(lf.stall == "compute" and lf.flops > 0 for lf in dots)
+    scopes = {f.name for lf in ks.leaves for f in lf.frames
+              if f.kind == GPU_FUNC}
+    assert {"_init", "_block", "_finish"} <= scopes
 
 
 def test_source_info_failure_propagates(monkeypatch, recovered):

@@ -24,8 +24,8 @@ def _cfg(**kw):
 def test_absorbed_decode_equals_expanded_attention():
     """One decode step over the latent rows (W_kb into the query, W_vb
     into the output) gives what attention with K and V expanded from the
-    latent gives at that position; slots from the position on are not
-    read."""
+    latent gives at that position; slots from the position on, and the
+    other layers' caches, are not read."""
     cfg = _cfg()
     p = mla_mod.init_mla_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     B, S, smax = 2, 11, 16
@@ -35,9 +35,11 @@ def test_absorbed_decode_equals_expanded_attention():
     caches = [jnp.concatenate([r[:, :S], jax.random.normal(
         jax.random.PRNGKey(2), (B, smax - S) + r.shape[2:])], axis=1)
         for r in rows]
+    # stacked over layers: this one is layer 1 of 3
+    caches = [jnp.stack([c + 7.0, c, c - 7.0]) for c in caches]
     got, new = mla_mod.mla_decode(p, h[:, S:], cfg,
                                   jnp.full((B, 1), S, jnp.int32), *caches,
-                                  jnp.int32(S))
+                                  jnp.int32(S), jnp.int32(1))
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want[:, S]),
                                rtol=1e-5, atol=1e-5)
     for n, r in zip(new, rows):
@@ -131,10 +133,29 @@ def test_latent_cache_aliased_in_place():
         == 12 * B * cfg.moe.top_k
 
 
+@pytest.mark.parametrize("max_len", [16, 128, 129])
+def test_latent_caches_come_in_whole_slot_tiles(max_len):
+    """Made for a batch or grown from its prefill, the latent caches hold
+    max_len slots rounded up to whole 128-slot tiles, and both ways give
+    the same shapes."""
+    from repro.launch.serve import _grow_cache
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    made = jax.eval_shape(lambda: T.init_cache(cfg, 2, max_len))
+    grown = jax.eval_shape(lambda: _grow_cache(cfg, T.prefill(
+        params, cfg, jnp.zeros((2, 8), jnp.int32), opts=OPTS)[1], 2,
+        max_len, 8))
+    assert jax.tree.map(lambda a: a.shape, made) == \
+        jax.tree.map(lambda a: a.shape, grown)
+    slots = -(-max_len // 128) * 128
+    for e in ("d0", "e0"):
+        assert made[e]["ckv"].shape[2] == made[e]["kpe"].shape[2] == slots
+
+
 @pytest.mark.parametrize("steps", [1, 3])
 def test_prefill_then_decode_matches_prefill_of_the_whole(steps):
-    """Prefill then decode through the latent cache, the last step in
-    its last slot, gives the logits a prefill of the whole sequence
+    """Prefill then decode through the latent cache, the last step at
+    position max_len - 1, gives the logits a prefill of the whole sequence
     gives at its last position (the serving layer drops no row)."""
     from repro.launch.serve import _grow_cache
     cfg = _cfg()

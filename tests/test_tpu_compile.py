@@ -5,10 +5,13 @@ rules, VMEM limits or device memory, so every kernel used to pass its
 CPU tests while the chip's compiler refused two of them.  Here the TPU
 compiler, installed with libtpu, compiles for a *described* v5e chip:
 
-- the three Pallas kernels at the widths the deployments use, each
+- the four Pallas kernels at the widths the deployments use, each
   lowering to a ``tpu_custom_call``;
 - qwen2-1.5b's prefill step at published widths, whose compiled
-  footprint must fit one chip's 16 GiB.
+  footprint must fit one chip's 16 GiB;
+- the decode steps of the serving cells with their caches donated,
+  updated in place; moonlight's on the latent-attention kernel too,
+  which reads the stacked caches with no copy of them.
 
 The topology is described inside a fixture, never at import: only one
 process may load libtpu, and under pytest-xdist each worker imports
@@ -69,8 +72,23 @@ def _ssm(spec):
             spec((2, 2048, 16)), spec((2, 2048, 16)))
 
 
-@pytest.mark.parametrize("build", [_flash, _decode, _ssm],
-                         ids=["flash_attention", "flash_decode", "ssm_scan"])
+def _mla_decode(spec, S=1280):
+    from repro.kernels.mla_decode import mla_decode_fwd
+    # moonlight-16b-a3b's decode: 16 heads, latent 512, rope 64, batch
+    # 128, 1,280 slots (the cell's), the 26 MoE layers' caches stacked
+    B, H, r, rope, L = 128, 16, 512, 64, 26
+    return (functools.partial(mla_decode_fwd, scale=192 ** -0.5),
+            spec((B, H, r)), spec((B, H, rope)), spec((B, r)),
+            spec((B, rope)), spec((L, B, S, r)), spec((L, B, S, rope)),
+            spec((), jnp.int32), spec((), jnp.int32))
+
+
+@pytest.mark.parametrize("build", [
+    _flash, _decode, _ssm, _mla_decode,
+    # a serving length that is not a multiple of the slot block
+    functools.partial(_mla_decode, S=1256)],
+    ids=["flash_attention", "flash_decode", "ssm_scan", "mla_decode",
+         "mla_decode-1256-slots"])
 def test_kernel_compiles_for_v5e(one_chip, build):
     fn, *args = build(functools.partial(_spec, one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
@@ -160,3 +178,89 @@ def test_moonlight_decode_updates_the_latent_cache_in_place(one_chip):
     layer_bytes = cache_bytes // 27
     assert mem.temp_size_in_bytes < 3 * layer_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _producers(hlo: str):
+    """{instruction name: (opcode, operand names)} of an HLO text."""
+    import re
+    out = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\((.*?)\)", line)
+        if m:
+            out[m.group(1)] = (m.group(2),
+                               re.findall(r"%([\w.-]+)", m.group(3)))
+    return out
+
+
+def test_moonlight_decode_kernel_reads_the_stacked_caches_in_place(
+        one_chip, monkeypatch):
+    """On the latent-attention kernel (the path a TPU takes), moonlight's
+    decode step at its serving cell's size still aliases the donated
+    cache with less scratch than one layer's latent cache, and each
+    layer's kernel call is handed the stacked caches themselves: its
+    cache operands come from the parameters through bitcasts (the roped
+    keys' slot-minor view) and, at most, an asynchronous move of a whole
+    stack to on-chip memory, never from a slice or a copy of one."""
+    _check_moonlight_decode_on_the_kernel(one_chip, monkeypatch, 1280)
+
+
+def test_moonlight_decode_kernel_at_a_length_off_the_slot_tile(
+        one_chip, monkeypatch):
+    """The same at a serving length off the 128-slot tile (prompt 256 +
+    1,000 generated): the latent caches are made in whole tiles, 1,280
+    slots, so the chip keeps the roped keys slot-minor and no step
+    relayouts them for the kernel."""
+    cache = _check_moonlight_decode_on_the_kernel(one_chip, monkeypatch,
+                                                  1256)
+    assert cache["e0"]["ckv"].shape[2] == cache["e0"]["kpe"].shape[2] == 1280
+
+
+def _check_moonlight_decode_on_the_kernel(one_chip, monkeypatch, max_len):
+    import dataclasses
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch import steps as steps_mod
+    from repro.models import mla as mla_mod
+    from repro.models import transformer as T
+    monkeypatch.setattr(mla_mod, "_use_kernel", lambda: True)
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"),
+                              experts_held=8)
+    batch = 128
+    opts = T.ModelOptions(q_chunk=256, kv_chunk=256, loss_chunk=256)
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+    params = specs(jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = specs(jax.eval_shape(lambda: T.init_cache(cfg, batch, max_len)))
+    step = jax.jit(steps_mod.make_decode_step(cfg, None, opts),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache, _spec(one_chip, (), jnp.int32),
+                          token=_spec(one_chip, (batch,), jnp.int32)
+                          ).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert cache_bytes <= mem.alias_size_in_bytes < cache_bytes + 1024
+    layer_ckv_bytes = batch * max_len * cfg.kv_lora_rank * 2
+    assert mem.temp_size_in_bytes < layer_ckv_bytes
+    hlo = compiled.as_text()
+    prods = _producers(hlo)
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "/mla_scores/mla_decode" in line]
+    assert len(calls) == 2            # the dense layer's and the scan's
+    for line in calls:
+        name = line.split("=")[0].strip().removeprefix("ROOT ").lstrip("%")
+        operands = prods[name][1]
+        # the stacked caches: (1 or 26, 128, max_len, 512) and its view
+        # (., 128, 64, max_len)
+        for op in operands[-2:]:
+            while prods.get(op, ("",))[0] in ("bitcast", "copy-done",
+                                               "copy-start"):
+                op = prods[op][1][0]
+            assert prods.get(op, ("parameter",))[0] in (
+                "parameter", "get-tuple-element"), (name, op, prods.get(op))
+    return cache
